@@ -944,6 +944,8 @@ def main() -> None:
         # Must land before the first jax import (all benches import lazily).
         os.environ.setdefault(
             "XLA_FLAGS", "--xla_force_host_platform_device_count=8")
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
     scale = FULL if args.full else SMOKE if args.smoke else QUICK
     names = args.benches or list(BENCHES)
     os.makedirs(RESULTS_DIR, exist_ok=True)

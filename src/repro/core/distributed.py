@@ -53,8 +53,8 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.core import forest as FO
 from repro.core import guards as GU
@@ -495,7 +495,7 @@ def make_distributed_boost_step(mesh: Mesh, cfg: GBDTConfig, *,
     step = shard_map(local_step, mesh=mesh,
                      in_specs=(f_spec, row_spec, y_spec, P()),
                      out_specs=(f_spec, tree_specs),
-                     check_rep=False)
+                     check_vma=False)
     return jax.jit(step, donate_argnums=(0,))
 
 
@@ -513,7 +513,7 @@ def make_distributed_eval(mesh: Mesh, cfg: GBDTConfig, *,
                                   F_l.shape[1])
 
     fn = shard_map(local_eval, mesh=mesh, in_specs=(f_spec, y_spec),
-                   out_specs=P(), check_rep=False)
+                   out_specs=P(), check_vma=False)
     return jax.jit(fn)
 
 

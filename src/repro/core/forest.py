@@ -693,8 +693,12 @@ def predict_staged(pf: PackedForest, codes: jax.Array, *, mode="jnp"
     trajectory — meant for validation-sized inputs (model selection,
     learning curves), not the serving path.
     """
-    return _staged_scan(codes, pf.feat, pf.thr, pf.left, pf.right, pf.leaf,
-                        pf.out_col, pf.base, pf.lr, depth=pf.depth,
+    # Leaves are scaled outside the scan's program, as `predict_raw` does
+    # (`kernels.ref.scaled_leaves`); the in-scan factor 1.0 is then exact.
+    from repro.kernels import ref
+    return _staged_scan(codes, pf.feat, pf.thr, pf.left, pf.right,
+                        ref.scaled_leaves(pf.lr, pf.leaf), pf.out_col,
+                        pf.base, jnp.float32(1.0), depth=pf.depth,
                         trees_per_round=pf.trees_per_round,
                         mode=H.resolve_kernel_mode(mode))
 

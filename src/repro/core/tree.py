@@ -19,6 +19,7 @@ import jax.numpy as jnp
 
 from repro.core import histogram as H
 from repro.core import split as S
+from repro.kernels import ref
 
 
 class Tree(NamedTuple):
@@ -500,20 +501,22 @@ class Forest(NamedTuple):
 
 
 @functools.partial(jax.jit, static_argnames=("depth",))
-def _forest_apply(feat, thr, value, codes, lr, base, *, depth: int):
+def _forest_apply(feat, thr, value_s, codes, base, *, depth: int):
     def body(acc, tree_arrays):
         f, t, v = tree_arrays
         pos = tree_leaf_index(f, t, codes, depth=depth)
-        return acc + lr * v[pos], None
+        return acc + v[pos], None
 
     n = codes.shape[0]
-    init = jnp.broadcast_to(base, (n, value.shape[-1])).astype(jnp.float32)
-    out, _ = jax.lax.scan(body, init, (feat, thr, value))
+    init = jnp.broadcast_to(base, (n, value_s.shape[-1])).astype(jnp.float32)
+    out, _ = jax.lax.scan(body, init, (feat, thr, value_s))
     return out
 
 
 def predict_forest(forest: Forest, codes: jax.Array, lr: float,
                    base_score: jax.Array) -> jax.Array:
-    """Raw ensemble scores F(x) = base + lr * sum_t f_t(x)."""
-    return _forest_apply(forest.feat, forest.thr, forest.value, codes,
-                         jnp.float32(lr), base_score, depth=forest.depth)
+    """Raw ensemble scores F(x) = base + sum_t (lr * f_t)(x), with the
+    leaves scaled once up front (`kernels.ref.scaled_leaves`)."""
+    return _forest_apply(forest.feat, forest.thr,
+                         ref.scaled_leaves(lr, forest.value), codes,
+                         base_score, depth=forest.depth)

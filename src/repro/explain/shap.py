@@ -39,7 +39,10 @@ def expected_values(pf, pack: Optional[PathPack] = None) -> jax.Array:
     that pair with path-dependent SHAP.
     """
     pack = build_path_pack(pf) if pack is None else pack
-    e_tree = jnp.einsum("tl,tlw->tw", pack.leaf_weight, pack.leaf)  # (T, w)
+    # HIGHEST: a TPU's default f32 matmul is one bf16 pass, which moves the
+    # base value by ~1e-3 relative and breaks local accuracy.
+    e_tree = jnp.einsum("tl,tlw->tw", pack.leaf_weight, pack.leaf,
+                        precision=jax.lax.Precision.HIGHEST)        # (T, w)
     if pf.leaf_width == pf.n_outputs:
         return pf.base + pf.lr * jnp.sum(e_tree, axis=0)
     scat = jax.ops.segment_sum(e_tree[:, 0], pf.out_col.astype(jnp.int32),

@@ -52,15 +52,16 @@ def _hist_kernel(codes_ref, node_ref, stats_ref, out_ref, *, n_bins: int,
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    code = codes_ref[0, :].astype(jnp.int32)              # (TN,)
-    seg = node_ref[:].astype(jnp.int32) * n_bins + code   # (TN,)
+    code = codes_ref[...].astype(jnp.int32)               # (1, TN)
+    seg = node_ref[...].astype(jnp.int32) * n_bins + code  # (1, TN)
     rel = seg - nb * nb_chunk
-    tn = code.shape[0]
-    cols = jax.lax.broadcasted_iota(jnp.int32, (tn, nb_chunk), 1)
-    onehot = (rel[:, None] == cols).astype(jnp.float32)   # (TN, NBC)
+    tn = code.shape[1]
+    rows = jax.lax.broadcasted_iota(jnp.int32, (nb_chunk, tn), 0)
+    onehot_t = (rel == rows).astype(jnp.float32)          # (NBC, TN)
     out_ref[...] += jax.lax.dot_general(
-        onehot, stats_ref[...],
-        dimension_numbers=(((0,), (0,)), ((), ())),
+        onehot_t, stats_ref[...],
+        dimension_numbers=(((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)               # (NBC, C)
 
 
@@ -69,7 +70,7 @@ def _hist_kernel(codes_ref, node_ref, stats_ref, out_ref, *, n_bins: int,
     static_argnames=("n_nodes", "n_bins", "row_tile", "nb_chunk", "interpret"))
 def histogram_pallas(codes_t: jax.Array, node_pos: jax.Array, stats: jax.Array,
                      *, n_nodes: int, n_bins: int, row_tile: int = 256,
-                     nb_chunk: int = 2048, interpret: bool = True) -> jax.Array:
+                     nb_chunk: int = 2048, interpret: bool) -> jax.Array:
     """Raw kernel entry (padded inputs required — use `ops.histogram`).
 
     Args:
@@ -85,32 +86,36 @@ def histogram_pallas(codes_t: jax.Array, node_pos: jax.Array, stats: jax.Array,
     assert nb_total % nb_chunk == 0 and n % row_tile == 0
     grid = (m, nb_total // nb_chunk, n // row_tile)
 
+    # Mosaic blocks the last two dims in (8, 128) tiles or whole: a unit
+    # middle axis makes every (1, row_tile) code / node block legal.
     return pl.pallas_call(
         functools.partial(_hist_kernel, n_bins=n_bins, nb_chunk=nb_chunk),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, row_tile), lambda f, nb, t: (f, t)),
-            pl.BlockSpec((row_tile,), lambda f, nb, t: (t,)),
+            pl.BlockSpec((None, 1, row_tile), lambda f, nb, t: (f, 0, t)),
+            pl.BlockSpec((1, row_tile), lambda f, nb, t: (0, t)),
             pl.BlockSpec((row_tile, c), lambda f, nb, t: (t, 0)),
         ],
-        out_specs=pl.BlockSpec((1, nb_chunk, c), lambda f, nb, t: (f, nb, 0)),
+        out_specs=pl.BlockSpec((None, nb_chunk, c),
+                               lambda f, nb, t: (f, nb, 0)),
         out_shape=jax.ShapeDtypeStruct((m, nb_total, c), jnp.float32),
         interpret=interpret,
-    )(codes_t, node_pos, stats)
+    )(codes_t.reshape(m, 1, n), node_pos.reshape(1, n), stats)
 
 
 HIST_DTYPES = ("float32", "bfloat16")
 
 
 def _hist_tiles_kernel(codes_ref, stats_ref, out_ref, *, n_bins: int,
-                       compute_dtype):
-    code = codes_ref[0, :].astype(jnp.int32)              # (TN,)
-    tn = code.shape[0]
-    cols = jax.lax.broadcasted_iota(jnp.int32, (tn, n_bins), 1)
-    onehot = (code[:, None] == cols).astype(compute_dtype)  # (TN, B)
-    out_ref[0, 0] = jax.lax.dot_general(
-        onehot, stats_ref[...].astype(compute_dtype),
-        dimension_numbers=(((0,), (0,)), ((), ())),
+                       compute_dtype, precision):
+    code = codes_ref[...].astype(jnp.int32)               # (1, TN)
+    tn = code.shape[1]
+    rows = jax.lax.broadcasted_iota(jnp.int32, (n_bins, tn), 0)
+    onehot_t = (code == rows).astype(compute_dtype)       # (B, TN)
+    out_ref[...] = jax.lax.dot_general(
+        onehot_t, stats_ref[...].astype(compute_dtype),
+        dimension_numbers=(((1,), (0,)), ((), ())),
+        precision=precision,
         preferred_element_type=jnp.float32)               # (B, C)
 
 
@@ -119,7 +124,7 @@ def _hist_tiles_kernel(codes_ref, stats_ref, out_ref, *, n_bins: int,
     static_argnames=("n_bins", "row_tile", "hist_dtype", "interpret"))
 def hist_tiles_pallas(codes_t: jax.Array, stats: jax.Array, *, n_bins: int,
                       row_tile: int = 256, hist_dtype: str = "float32",
-                      interpret: bool = True) -> jax.Array:
+                      interpret: bool) -> jax.Array:
     """Raw per-tile kernel entry (node-contiguous gathered inputs required —
     use `ops.histogram_splits_level` / `ops.node_histogram`).
 
@@ -147,19 +152,23 @@ def hist_tiles_pallas(codes_t: jax.Array, stats: jax.Array, *, n_bins: int,
     if hist_dtype not in HIST_DTYPES:
         raise ValueError(f"unknown hist_dtype {hist_dtype!r}; "
                          f"expected one of {HIST_DTYPES}")
-    compute_dtype = jnp.bfloat16 if hist_dtype == "bfloat16" else jnp.float32
+    bf16 = hist_dtype == "bfloat16"
+    compute_dtype = jnp.bfloat16 if bf16 else jnp.float32
+    # fp32 stats must not be truncated to one bf16 MXU pass.
+    precision = None if bf16 else jax.lax.Precision.HIGHEST
     n_tiles = s // row_tile
     grid = (m, n_tiles)
 
     return pl.pallas_call(
         functools.partial(_hist_tiles_kernel, n_bins=n_bins,
-                          compute_dtype=compute_dtype),
+                          compute_dtype=compute_dtype, precision=precision),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, row_tile), lambda f, t: (f, t)),
+            pl.BlockSpec((None, 1, row_tile), lambda f, t: (f, 0, t)),
             pl.BlockSpec((row_tile, c), lambda f, t: (t, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, n_bins, c), lambda f, t: (f, t, 0, 0)),
+        out_specs=pl.BlockSpec((None, None, n_bins, c),
+                               lambda f, t: (f, t, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((m, n_tiles, n_bins, c), jnp.float32),
         interpret=interpret,
-    )(codes_t, stats)
+    )(codes_t.reshape(m, 1, s), stats)

@@ -1,8 +1,10 @@
 """Jit'd public wrappers around the Pallas kernels (padding, layout, dispatch).
 
-Each op pads inputs to kernel tile multiples, calls the kernel (interpret mode
-on CPU — the TARGET is TPU, where ``interpret=False`` runs the compiled Mosaic
-kernel), and unpads.  ``*_ref`` semantics are defined in `repro.kernels.ref`.
+Each op pads inputs to kernel tile multiples, calls the kernel and unpads.
+The GBDT ops take ``interpret`` with no default: ``False`` runs the compiled
+Mosaic kernel (TPU), ``True`` the Pallas interpreter (CPU parity tests), so
+a caller cannot land in the interpreter on the chip by omission.  ``*_ref``
+semantics are defined in `repro.kernels.ref`.
 """
 from __future__ import annotations
 
@@ -65,7 +67,7 @@ def _pad_to(x: jax.Array, mult: int, axis: int, value=0):
 def histogram(codes: jax.Array, node_pos: jax.Array, stats: jax.Array, *,
               n_nodes: int, n_bins: int, row_tile: int = 256,
               nb_chunk: int = 2048, lane_pad: int | None = None,
-              interpret: bool = True) -> jax.Array:
+              interpret: bool) -> jax.Array:
     """(n, m) codes + (n,) nodes + (n, c) stats -> (n_nodes, m, n_bins, c).
 
     Padded rows carry zero stats (and node 0 / bin 0), contributing nothing.
@@ -94,7 +96,7 @@ def histogram(codes: jax.Array, node_pos: jax.Array, stats: jax.Array, *,
 def split_scan(hist: jax.Array, lam: jax.Array, min_data: jax.Array,
                feature_mask: jax.Array | None = None, *, n_nodes: int,
                n_bins: int, m_tile: int = 8, lane_pad: int | None = None,
-               interpret: bool = True):
+               interpret: bool):
     """(m, n_nodes * n_bins, c) histograms -> per-node (best_gain, best_idx).
 
     ``best_idx`` encodes ``feature * n_bins + bin``; gain is -inf for nodes
@@ -124,7 +126,7 @@ def histogram_splits(codes: jax.Array, node_pos: jax.Array, stats: jax.Array,
                      feature_mask: jax.Array | None = None, *, n_nodes: int,
                      n_bins: int, row_tile: int = 256, nb_chunk: int = 2048,
                      m_tile: int = 8, lane_pad: int | None = None,
-                     interpret: bool = True):
+                     interpret: bool):
     """Fused hot path: histogram kernel -> split-scan kernel, no transpose.
 
     The intermediate histograms stay in the kernels' native
@@ -197,7 +199,7 @@ def histogram_splits_level(codes: jax.Array, stats: jax.Array,
                            row_tile: int = 256, m_tile: int = 8,
                            lane_pad: int | None = None,
                            hist_dtype: str = "float32",
-                           interpret: bool = True):
+                           interpret: bool):
     """Fused partitioned hot path: tiles kernel -> sibling combine -> split scan.
 
     The node-partitioned replacement for `histogram_splits`: rows are
@@ -273,7 +275,7 @@ def histogram_splits_level(codes: jax.Array, stats: jax.Array,
 def node_histogram(codes_g: jax.Array, stats_g: jax.Array, *, n_bins: int,
                    row_tile: int = 256, lane_pad: int | None = None,
                    hist_dtype: str = "float32",
-                   interpret: bool = True) -> jax.Array:
+                   interpret: bool) -> jax.Array:
     """Single-node histogram over gathered rows: ``(m, n_bins, c)``.
 
     The leaf-wise grower's kernel-path builder: ``codes_g`` (S, m) /
@@ -304,7 +306,7 @@ def forest_apply(F_init: jax.Array, codes: jax.Array, feat: jax.Array,
                  leaf: jax.Array, out_col: jax.Array,
                  lr, *, depth: int, row_tile: int = 256,
                  lane_pad: int | None = None,
-                 interpret: bool = True) -> jax.Array:
+                 interpret: bool) -> jax.Array:
     """Packed-forest traversal: ``F_init + lr * sum_t tree_t(codes)``.
 
     Pads rows to ``row_tile`` and the feature / node / leaf-width / output
@@ -329,11 +331,10 @@ def forest_apply(F_init: jax.Array, codes: jax.Array, feat: jax.Array,
     right_p = _pad_to(right.astype(jnp.int32), lane_pad, axis=1)
     leaf_p = _pad_to(_pad_to(leaf.astype(jnp.float32), lane_pad, axis=1),
                      lane_pad, axis=2)
-    params = jnp.asarray([[lr]], jnp.float32)
-    out = forest_traverse_pallas(params, out_col.astype(jnp.int32)[:, None],
-                                 F_p, codes_p, feat_p, thr_p, left_p,
-                                 right_p, leaf_p,
-                                 depth=depth, leaf_width=w,
+    lr_row = jnp.full((1, leaf_p.shape[2]), lr, jnp.float32)
+    out = forest_traverse_pallas(lr_row, out_col.astype(jnp.int32), F_p,
+                                 codes_p, feat_p, thr_p, left_p, right_p,
+                                 leaf_p, depth=depth, leaf_width=w,
                                  row_tile=row_tile, interpret=interpret)
     return out[:n, :d]
 
@@ -347,14 +348,14 @@ def forest_apply_quant(F_init: jax.Array, codes: jax.Array, feat: jax.Array,
                        leaf: jax.Array, leaf_scale: jax.Array,
                        out_col: jax.Array, lr, *, depth: int,
                        row_tile: int = 256, lane_pad: int | None = None,
-                       interpret: bool = True) -> jax.Array:
+                       interpret: bool) -> jax.Array:
     """Quantized packed-forest traversal (storage-compressed serving path).
 
     Same padding policy as `forest_apply`; the leaf tensor is padded in its
     OWN dtype (int8 / bfloat16) so the kernel's VMEM working set keeps the
     compression win, thresholds are widened to int32 on the way in (uint8
     bin codes — the walk is split-exact), and dequantization happens
-    in-kernel against the per-tree SMEM scale.  Semantics contract:
+    in-kernel against the per-tree scale row.  Semantics contract:
     `ref.forest_apply_quant_ref`.
     """
     n, m = codes.shape
@@ -370,10 +371,11 @@ def forest_apply_quant(F_init: jax.Array, codes: jax.Array, feat: jax.Array,
     left_p = _pad_to(left.astype(jnp.int32), lane_pad, axis=1)
     right_p = _pad_to(right.astype(jnp.int32), lane_pad, axis=1)
     leaf_p = _pad_to(_pad_to(leaf, lane_pad, axis=1), lane_pad, axis=2)
-    params = jnp.asarray([[lr]], jnp.float32)
-    scale = leaf_scale.astype(jnp.float32).reshape(-1, 1)
-    out = forest_traverse_quant_pallas(params,
-                                       out_col.astype(jnp.int32)[:, None],
+    w_pad = leaf_p.shape[2]
+    lr_row = jnp.full((1, w_pad), lr, jnp.float32)
+    scale = jnp.broadcast_to(leaf_scale.astype(jnp.float32).reshape(-1, 1, 1),
+                             (leaf.shape[0], 1, w_pad))
+    out = forest_traverse_quant_pallas(lr_row, out_col.astype(jnp.int32),
                                        scale, F_p, codes_p, feat_p, thr_p,
                                        left_p, right_p, leaf_p,
                                        depth=depth, leaf_width=w,
@@ -388,8 +390,8 @@ def forest_apply_quant(F_init: jax.Array, codes: jax.Array, feat: jax.Array,
 def tree_shap(codes: jax.Array, slot_feat: jax.Array, slot_lo: jax.Array,
               slot_hi: jax.Array, slot_z: jax.Array, leaf: jax.Array,
               out_col: jax.Array, lr, *, n_outputs: int, depth: int,
-              row_tile: int = 64, lane_pad: int | None = None,
-              interpret: bool = True) -> jax.Array:
+              row_tile: int | None = None, lane_pad: int | None = None,
+              interpret: bool) -> jax.Array:
     """Path-dependent TreeSHAP: ``lr * sum_t shap_t(codes)`` as (n, m, d).
 
     Takes the `explain.paths.build_path_pack` slot tensors in their native
@@ -400,11 +402,20 @@ def tree_shap(codes: jax.Array, slot_feat: jax.Array, slot_lo: jax.Array,
     Padded leaves/slots are inert null players (``o = z = 1``, zero leaf
     values) — exactly invariant, so the result is bit-identical to the
     unpadded oracle.  Semantics contract: `ref.tree_shap_ref`.
+    ``row_tile=None`` sizes the row tile so the ``(row_tile, M_pad, d_pad)``
+    output block stays near 2 MiB of VMEM (64 rows at small widths, 8 at
+    m=100, d=512).
     """
     n, m = codes.shape
     d = n_outputs
     w = leaf.shape[2]
     lane_pad = _resolve_lane_pad(lane_pad, interpret)
+    d_pad = d + (-d) % lane_pad
+    if row_tile is None:
+        row_bytes = (m + (-m) % lane_pad) * d_pad * 4
+        row_tile = 64
+        while row_tile > 8 and row_tile * row_bytes > 2 ** 21:
+            row_tile //= 2
     codes_p = _pad_to(_pad_to(codes.astype(jnp.int32), row_tile, axis=0),
                       lane_pad, axis=1)
     # Slot tensors: pad the leaf axis with inert slots, then slot-major
@@ -423,9 +434,8 @@ def tree_shap(codes: jax.Array, slot_feat: jax.Array, slot_lo: jax.Array,
     z_p = lay_out(slot_z, 1.0, jnp.float32)
     leaf_p = _pad_to(_pad_to(leaf.astype(jnp.float32), lane_pad, axis=1),
                      lane_pad, axis=2)
-    d_pad = d + (-d) % lane_pad
-    params = jnp.asarray([[lr]], jnp.float32)
-    out = shap_pallas(params, out_col.astype(jnp.int32)[:, None], codes_p,
+    lr_row = jnp.full((1, leaf_p.shape[2]), lr, jnp.float32)
+    out = shap_pallas(lr_row, out_col.astype(jnp.int32), codes_p,
                       sf_p, lo_p, hi_p, z_p, leaf_p, depth=depth,
                       leaf_width=w, d_pad=d_pad, row_tile=row_tile,
                       interpret=interpret)

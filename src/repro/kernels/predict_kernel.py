@@ -11,10 +11,9 @@ unified node id space with explicit ``left``/``right`` child pointers
 leaf-wise best-first trees alike.  For one row tile and one tree, every
 level maintains the node id ``pos`` of each row and advances it with
 
-    sel    = onehot(pos)  @ onehot(feat)          (TN, N) @ (N, M)
+    sel    = onehot(pos)  @ onehot(feat)^T        (TN, N) @ (M, N)^T
     code   = sum_f sel * codes                    (TN, 1)
-    thr    = onehot(pos)  @ thr                   (TN, 1)
-    l, r   = onehot(pos)  @ [left | right]        (TN, 2) slot gathers
+    thr, l, r = onehot(pos) @ [thr|left|right]^T  (TN, 8) slot gathers
     pos   <- code > thr ? r : l
 
 After ``depth`` levels ``pos`` is the terminal node (self-loops make extra
@@ -29,12 +28,12 @@ the kernel is bit-compatible with the gather-based reference
 
 Grid = ``(row_tiles, trees)``; the output block for a row tile is revisited
 across the sequential tree axis (canonical Pallas accumulation: init from the
-``F_init`` scores at ``t == 0``, then ``out += lr * contribution`` per tree —
-the same add order as the scan-based reference, so accumulation is also
+``F_init`` scores at ``t == 0``, then ``out += (lr * leaf)[walk]`` per tree
+— the same add order as the scan-based reference, so accumulation is also
 bit-identical).  VMEM working set per step: codes tile (TN x M x 4B) + node
-tensors (5 x N x 4B) + leaf block (N x W x 4B) + out/init tiles
+tensors (4 x N x 4B) + leaf block (N x W x 4B) + out/init tiles
 (2 x TN x D x 4B) + the (TN, max(M, N)) one-hot planes — with TN=256,
-M<=512, N=127 (depth-6 heap), D<=128 that is ~2 MB, comfortably inside
+M=128, N=128 (depth-6 forest), D=512 that is ~3 MB double-buffered, inside
 16 MB VMEM.  Versus the heap-walk kernel this pays a ~2x wider one-hot
 plane per level (N vs the level width) in exchange for topology freedom.
 """
@@ -48,79 +47,23 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _forest_kernel(params_ref, col_ref, init_ref, codes_ref, feat_ref,
-                   thr_ref, left_ref, right_ref, leaf_ref, out_ref, *,
-                   depth: int, leaf_width: int):
-    t = pl.program_id(1)
-
-    @pl.when(t == 0)
-    def _init():
-        out_ref[...] = init_ref[...]
-
-    lr = params_ref[0, 0]
-    codes = codes_ref[...].astype(jnp.float32)             # (TN, M)
-    tn, m_pad = codes.shape
-    n_pad = feat_ref.shape[1]                              # node id space
-    feat_all = feat_ref[0, :]                              # (N,)
-    thr_all = thr_ref[0, :].astype(jnp.float32)
-    left_all = left_ref[0, :].astype(jnp.float32)          # exact small ints
-    right_all = right_ref[0, :].astype(jnp.float32)
-    feat_oh = (feat_all[:, None] == jax.lax.broadcasted_iota(
-        jnp.int32, (n_pad, m_pad), 1)).astype(jnp.float32)
-    ptrs = jnp.stack([thr_all, left_all, right_all], axis=1)  # (N, 3)
-    pos = jnp.zeros((tn, 1), jnp.int32)                    # node id per row
-
-    for _ in range(depth):
-        pos_oh = (pos == jax.lax.broadcasted_iota(
-            jnp.int32, (tn, n_pad), 1)).astype(jnp.float32)  # (TN, N)
-        sel = jax.lax.dot_general(                         # (TN, M) row's split
-            pos_oh, feat_oh,
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        code = jnp.sum(sel * codes, axis=1, keepdims=True)  # (TN, 1) exact
-        tlr = jax.lax.dot_general(                         # (TN, 3) thr/l/r
-            pos_oh, ptrs,
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        go_right = code > tlr[:, 0:1]
-        pos = jnp.where(go_right, tlr[:, 2:3], tlr[:, 1:2]).astype(jnp.int32)
-
-    l_pad = leaf_ref.shape[1]
-    leaf_oh = (pos == jax.lax.broadcasted_iota(
-        jnp.int32, (tn, l_pad), 1)).astype(jnp.float32)
-    pred = jax.lax.dot_general(                            # (TN, W) leaf block
-        leaf_oh, leaf_ref[0],
-        dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-
-    # Placement matrix: row i of the leaf block lands in output column
-    # out_col + i; rows beyond the real leaf_width are zero padding.
-    col = col_ref[0, 0]
-    w_pad, d_pad = pred.shape[1], out_ref.shape[1]
-    rows = jax.lax.broadcasted_iota(jnp.int32, (w_pad, d_pad), 0)
-    cols = jax.lax.broadcasted_iota(jnp.int32, (w_pad, d_pad), 1)
-    place = ((rows < leaf_width) & (rows + col == cols)).astype(jnp.float32)
-    out_ref[...] += lr * jax.lax.dot_general(
-        pred, place,
-        dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+_HI = jax.lax.Precision.HIGHEST    # fp32 operands: no single bf16 MXU pass
 
 
-def _forest_quant_kernel(params_ref, col_ref, scale_ref, init_ref, codes_ref,
-                         feat_ref, thr_ref, left_ref, right_ref, leaf_ref,
-                         out_ref, *, depth: int, leaf_width: int):
-    """Quantized-storage variant of `_forest_kernel` (fp32 accumulation).
+def _dot(a, b, contract):
+    return jax.lax.dot_general(a, b, dimension_numbers=(contract, ((), ())),
+                               precision=_HI,
+                               preferred_element_type=jnp.float32)
 
-    Identical walk — thresholds arrive as exact small integers whatever
-    their storage dtype (bin codes < 256), so the branch decisions are
-    bit-identical to the fp32 kernel — plus one in-VMEM dequantization of
-    the int8/bf16 leaf block (``astype(f32) * scale_ref[t]``) before the
-    terminal one-hot gather.  Dequantizing the block before the exact 0/1
-    gather equals gathering then dequantizing, so the kernel matches
-    `ref.forest_apply_quant_ref` bit-for-bit (asserted by the parity
-    tests).  The model's VMEM working set shrinks 4x (int8) / 2x (bf16) on
-    the leaf tensor — the traversal is memory-bound on exactly that
-    tensor.
+
+def _walk_and_place(col, init_ref, codes_ref, feat_ref, thr_ref, left_ref,
+                    right_ref, leaf, out_ref, *, depth: int,
+                    leaf_width: int):
+    """One (row tile, tree) grid step.
+
+    ``leaf`` is the tree's fp32 (N, W) block, already multiplied by the
+    learning rate, so the accumulating add is the only rounding after the
+    exact gathers (as in `ref.scaled_leaves`).
     """
     t = pl.program_id(1)
 
@@ -128,70 +71,132 @@ def _forest_quant_kernel(params_ref, col_ref, scale_ref, init_ref, codes_ref,
     def _init():
         out_ref[...] = init_ref[...]
 
-    lr = params_ref[0, 0]
     codes = codes_ref[...].astype(jnp.float32)             # (TN, M)
     tn, m_pad = codes.shape
     n_pad = feat_ref.shape[1]                              # node id space
-    feat_all = feat_ref[0, :]                              # (N,)
-    thr_all = thr_ref[0, :].astype(jnp.float32)
-    left_all = left_ref[0, :].astype(jnp.float32)          # exact small ints
-    right_all = right_ref[0, :].astype(jnp.float32)
-    feat_oh = (feat_all[:, None] == jax.lax.broadcasted_iota(
-        jnp.int32, (n_pad, m_pad), 1)).astype(jnp.float32)
-    ptrs = jnp.stack([thr_all, left_all, right_all], axis=1)  # (N, 3)
+    # Node tensors arrive as (1, N) rows; every one-hot is built with the
+    # node axis on lanes and contracted as ``A @ B^T``.
+    feat_oh_t = (jax.lax.broadcasted_iota(jnp.int32, (m_pad, n_pad), 0)
+                 == feat_ref[...]).astype(jnp.float32)     # (M, N)
+    k = jax.lax.broadcasted_iota(jnp.int32, (8, n_pad), 0)
+    ptrs_t = jnp.where(k == 0, thr_ref[...], jnp.where(
+        k == 1, left_ref[...], jnp.where(k == 2, right_ref[...], 0))
+    ).astype(jnp.float32)                                  # (8, N) thr/l/r
+    node_ids = jax.lax.broadcasted_iota(jnp.int32, (tn, n_pad), 1)
     pos = jnp.zeros((tn, 1), jnp.int32)                    # node id per row
 
     for _ in range(depth):
-        pos_oh = (pos == jax.lax.broadcasted_iota(
-            jnp.int32, (tn, n_pad), 1)).astype(jnp.float32)  # (TN, N)
-        sel = jax.lax.dot_general(                         # (TN, M) row's split
-            pos_oh, feat_oh,
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        pos_oh = (pos == node_ids).astype(jnp.float32)     # (TN, N)
+        sel = _dot(pos_oh, feat_oh_t, ((1,), (1,)))        # (TN, M) split
         code = jnp.sum(sel * codes, axis=1, keepdims=True)  # (TN, 1) exact
-        tlr = jax.lax.dot_general(                         # (TN, 3) thr/l/r
-            pos_oh, ptrs,
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        tlr = _dot(pos_oh, ptrs_t, ((1,), (1,)))           # (TN, 8) thr/l/r
         go_right = code > tlr[:, 0:1]
         pos = jnp.where(go_right, tlr[:, 2:3], tlr[:, 1:2]).astype(jnp.int32)
 
-    l_pad = leaf_ref.shape[1]
+    l_pad = leaf.shape[0]
     leaf_oh = (pos == jax.lax.broadcasted_iota(
         jnp.int32, (tn, l_pad), 1)).astype(jnp.float32)
-    leaf_deq = leaf_ref[0].astype(jnp.float32) * scale_ref[0, 0]
-    pred = jax.lax.dot_general(                            # (TN, W) leaf block
-        leaf_oh, leaf_deq,
-        dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    pred = _dot(leaf_oh, leaf, ((1,), (0,)))               # (TN, W) leaf block
 
-    col = col_ref[0, 0]
+    # Placement matrix: row i of the leaf block lands in output column
+    # out_col + i; rows beyond the real leaf_width are zero padding.
     w_pad, d_pad = pred.shape[1], out_ref.shape[1]
     rows = jax.lax.broadcasted_iota(jnp.int32, (w_pad, d_pad), 0)
     cols = jax.lax.broadcasted_iota(jnp.int32, (w_pad, d_pad), 1)
     place = ((rows < leaf_width) & (rows + col == cols)).astype(jnp.float32)
-    out_ref[...] += lr * jax.lax.dot_general(
-        pred, place,
-        dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    out_ref[...] += _dot(pred, place, ((1,), (0,)))
+
+
+# The learning rate and the dequant scale arrive as (1, W) rows, not SMEM
+# scalars: under the interpreter XLA would hoist a scalar factor out of the
+# gather matmul and fuse it into the accumulating add as an FMA.
+
+def _forest_kernel(col_ref, lr_ref, init_ref, codes_ref, feat_ref, thr_ref,
+                   left_ref, right_ref, leaf_ref, out_ref, *, depth: int,
+                   leaf_width: int):
+    _walk_and_place(col_ref[pl.program_id(1)], init_ref, codes_ref,
+                    feat_ref, thr_ref, left_ref, right_ref,
+                    leaf_ref[...] * lr_ref[...], out_ref, depth=depth,
+                    leaf_width=leaf_width)
+
+
+def _forest_quant_kernel(col_ref, lr_ref, scale_ref, init_ref, codes_ref,
+                         feat_ref, thr_ref, left_ref, right_ref, leaf_ref,
+                         out_ref, *, depth: int, leaf_width: int):
+    """Quantized-storage variant of `_forest_kernel` (fp32 accumulation).
+
+    Identical walk — thresholds arrive as exact small integers whatever
+    their storage dtype (bin codes < 256), so the branch decisions are
+    bit-identical to the fp32 kernel — plus one in-VMEM dequantization of
+    the int8/bf16 leaf block (``astype(f32) * scale``) before the learning
+    rate and the terminal one-hot gather, in the order the oracle rounds
+    them, so the kernel matches `ref.forest_apply_quant_ref` bit-for-bit
+    (asserted by the parity tests).  The model's VMEM working set shrinks
+    4x (int8) / 2x (bf16) on the leaf tensor — the traversal is
+    memory-bound on exactly that tensor.
+    """
+    leaf = leaf_ref[...].astype(jnp.float32) * scale_ref[...]
+    _walk_and_place(col_ref[pl.program_id(1)], init_ref, codes_ref,
+                    feat_ref, thr_ref, left_ref, right_ref,
+                    leaf * lr_ref[...], out_ref, depth=depth,
+                    leaf_width=leaf_width)
+
+
+def _traverse_call(kernel, out_col, lr_row, scale_rows, F_init, codes, feat,
+                   thr, left, right, leaf, *, row_tile: int,
+                   interpret: bool):
+    """Shared ``pallas_call`` of both traversal kernels.
+
+    The per-tree output column is scalar-prefetched into SMEM and read at
+    the tree's grid index; node tensors and the per-tree scale rows get a
+    unit middle axis so their (1, N) / (1, W) blocks are legal under
+    Mosaic's (8, 128) tiling rule.
+    """
+    n_pad, m_pad = codes.shape
+    n_trees, node_pad = feat.shape
+    l_pad, w_pad = leaf.shape[1], leaf.shape[2]
+    d_pad = F_init.shape[1]
+    assert n_pad % row_tile == 0 and l_pad == node_pad
+    assert node_pad < 2 ** 24                     # exact f32 pointers
+    per_tree = lambda width: pl.BlockSpec(  # noqa: E731
+        (None, 1, width), lambda r, t, col: (t, 0, 0))
+    rows = lambda width: pl.BlockSpec(  # noqa: E731
+        (row_tile, width), lambda r, t, col: (r, 0))
+    node3 = lambda x: x.reshape(n_trees, 1, node_pad)   # noqa: E731
+    scale = [] if scale_rows is None else [scale_rows]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(n_pad // row_tile, n_trees),
+        in_specs=[pl.BlockSpec((1, w_pad), lambda r, t, col: (0, 0))]
+        + [per_tree(w_pad)] * len(scale)
+        + [rows(d_pad), rows(m_pad)] + [per_tree(node_pad)] * 4
+        + [pl.BlockSpec((None, l_pad, w_pad), lambda r, t, col: (t, 0, 0))],
+        out_specs=rows(d_pad))
+    return pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((n_pad, d_pad), jnp.float32),
+        interpret=interpret,
+    )(out_col, lr_row, *scale, F_init, codes, node3(feat), node3(thr),
+      node3(left), node3(right), leaf)
 
 
 @functools.partial(
     jax.jit,
     static_argnames=("depth", "leaf_width", "row_tile", "interpret"))
-def forest_traverse_pallas(params: jax.Array, out_col: jax.Array,
+def forest_traverse_pallas(lr_row: jax.Array, out_col: jax.Array,
                            F_init: jax.Array, codes: jax.Array,
                            feat: jax.Array, thr: jax.Array,
                            left: jax.Array, right: jax.Array,
                            leaf: jax.Array,
                            *, depth: int, leaf_width: int,
                            row_tile: int = 256,
-                           interpret: bool = True) -> jax.Array:
+                           interpret: bool) -> jax.Array:
     """Raw kernel entry (padded inputs required — use `ops.forest_apply`).
 
     Args:
-      params:  (1, 1) float32 [learning_rate] (SMEM scalar).
-      out_col: (T, 1) int32 starting output column per tree (SMEM scalars).
+      lr_row:  (1, W) float32 learning rate repeated across the leaf width.
+      out_col: (T,) int32 starting output column per tree (scalar-prefetched
+               into SMEM).
       F_init:  (n, D) float32 initial scores, accumulated in place per tree.
       codes:   (n, M) int32 binned features.  n % row_tile == 0.
       feat, thr, left, right: (T, N) int32 node tensors; terminal nodes
@@ -202,87 +207,41 @@ def forest_traverse_pallas(params: jax.Array, out_col: jax.Array,
     Returns:
       (n, D) float32 scores ``F_init + lr * sum_t tree_t(codes)``.
     """
-    n_pad, m_pad = codes.shape
-    n_trees, node_pad = feat.shape
-    l_pad, w_pad = leaf.shape[1], leaf.shape[2]
-    d_pad = F_init.shape[1]
-    assert n_pad % row_tile == 0 and l_pad == node_pad
-    assert w_pad >= leaf_width and node_pad < 2 ** 24  # exact f32 pointers
-    grid = (n_pad // row_tile, n_trees)
-
-    return pl.pallas_call(
-        functools.partial(_forest_kernel, depth=depth, leaf_width=leaf_width),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1), lambda r, t: (t, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((row_tile, d_pad), lambda r, t: (r, 0)),
-            pl.BlockSpec((row_tile, m_pad), lambda r, t: (r, 0)),
-            pl.BlockSpec((1, node_pad), lambda r, t: (t, 0)),
-            pl.BlockSpec((1, node_pad), lambda r, t: (t, 0)),
-            pl.BlockSpec((1, node_pad), lambda r, t: (t, 0)),
-            pl.BlockSpec((1, node_pad), lambda r, t: (t, 0)),
-            pl.BlockSpec((1, l_pad, w_pad), lambda r, t: (t, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((row_tile, d_pad), lambda r, t: (r, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_pad, d_pad), jnp.float32),
-        interpret=interpret,
-    )(params, out_col, F_init, codes, feat, thr, left, right, leaf)
+    assert leaf.shape[2] >= leaf_width
+    kernel = functools.partial(_forest_kernel, depth=depth,
+                               leaf_width=leaf_width)
+    return _traverse_call(kernel, out_col, lr_row, None, F_init, codes, feat,
+                          thr, left, right, leaf, row_tile=row_tile,
+                          interpret=interpret)
 
 
 @functools.partial(
     jax.jit,
     static_argnames=("depth", "leaf_width", "row_tile", "interpret"))
-def forest_traverse_quant_pallas(params: jax.Array, out_col: jax.Array,
-                                 leaf_scale: jax.Array, F_init: jax.Array,
+def forest_traverse_quant_pallas(lr_row: jax.Array, out_col: jax.Array,
+                                 scale_rows: jax.Array, F_init: jax.Array,
                                  codes: jax.Array, feat: jax.Array,
                                  thr: jax.Array, left: jax.Array,
                                  right: jax.Array, leaf: jax.Array,
                                  *, depth: int, leaf_width: int,
                                  row_tile: int = 256,
-                                 interpret: bool = True) -> jax.Array:
+                                 interpret: bool) -> jax.Array:
     """Quantized raw kernel entry (padded inputs — use
     `ops.forest_apply_quant`).
 
-    Same grid/specs as `forest_traverse_pallas` plus a per-tree SMEM
-    dequant scale:
+    Same grid/specs as `forest_traverse_pallas` plus per-tree dequant
+    scales:
 
-      leaf_scale: (T, 1) float32 — dequant scale of each tree's leaf block
-               (all-ones for bfloat16 leaves).
+      scale_rows: (T, 1, W) float32 — each tree's dequant scale repeated
+               across the leaf width (all-ones for bfloat16 leaves).
       leaf:    (T, N, W) int8 or bfloat16 node-indexed leaf blocks,
                dequantized in VMEM; fp32 accumulation throughout.
       thr:     (T, N) int32 bin-code thresholds (uint8 storage is widened
                by the wrapper — the walk compares exact small integers).
     """
-    n_pad, m_pad = codes.shape
-    n_trees, node_pad = feat.shape
-    l_pad, w_pad = leaf.shape[1], leaf.shape[2]
-    d_pad = F_init.shape[1]
-    assert n_pad % row_tile == 0 and l_pad == node_pad
-    assert w_pad >= leaf_width and node_pad < 2 ** 24  # exact f32 pointers
-    grid = (n_pad // row_tile, n_trees)
-
-    return pl.pallas_call(
-        functools.partial(_forest_quant_kernel, depth=depth,
-                          leaf_width=leaf_width),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1), lambda r, t: (t, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1), lambda r, t: (t, 0),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((row_tile, d_pad), lambda r, t: (r, 0)),
-            pl.BlockSpec((row_tile, m_pad), lambda r, t: (r, 0)),
-            pl.BlockSpec((1, node_pad), lambda r, t: (t, 0)),
-            pl.BlockSpec((1, node_pad), lambda r, t: (t, 0)),
-            pl.BlockSpec((1, node_pad), lambda r, t: (t, 0)),
-            pl.BlockSpec((1, node_pad), lambda r, t: (t, 0)),
-            pl.BlockSpec((1, l_pad, w_pad), lambda r, t: (t, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((row_tile, d_pad), lambda r, t: (r, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_pad, d_pad), jnp.float32),
-        interpret=interpret,
-    )(params, out_col, leaf_scale, F_init, codes, feat, thr, left, right,
-      leaf)
+    assert leaf.shape[2] >= leaf_width
+    kernel = functools.partial(_forest_quant_kernel, depth=depth,
+                               leaf_width=leaf_width)
+    return _traverse_call(kernel, out_col, lr_row, scale_rows, F_init, codes,
+                          feat, thr, left, right, leaf, row_tile=row_tile,
+                          interpret=interpret)

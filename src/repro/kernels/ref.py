@@ -12,6 +12,21 @@ import jax
 import jax.numpy as jnp
 
 
+@jax.jit
+def scaled_leaves(lr, leaf: jax.Array) -> jax.Array:
+    """``lr * leaf``, rounded once, as an XLA program of its own.
+
+    Every tree-sum here (and the heap walk in `core.tree`) adds leaf values
+    that were multiplied by the learning rate *before* the gather, and runs
+    this multiply outside the accumulating program: inside one program XLA
+    may fuse the multiply into the add and contract the pair into an FMA,
+    in one program but not in another, which costs bit parity between
+    oracles and kernels by an ulp.  The kernels scale their VMEM leaf block
+    the same way before their exact one-hot gathers.
+    """
+    return jnp.asarray(lr, jnp.float32) * leaf.astype(jnp.float32)
+
+
 @functools.partial(jax.jit, static_argnames=("n_nodes", "n_bins"))
 def histogram_ref(codes: jax.Array, node_pos: jax.Array, stats: jax.Array,
                   *, n_nodes: int, n_bins: int) -> jax.Array:
@@ -49,6 +64,7 @@ def histogram_tiles_ref(codes_t: jax.Array, stats: jax.Array, *, n_bins: int,
     def per_tile(oh_t, st_t):                              # (m, TN, B), (TN, C)
         return jax.vmap(lambda oh: jax.lax.dot_general(
             oh, st_t, dimension_numbers=(((0,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32))(oh_t)
 
     out = jax.vmap(per_tile, in_axes=(1, 0))(onehot, stats_r)
@@ -113,7 +129,6 @@ def node_walk_ref(feat: jax.Array, thr: jax.Array, left: jax.Array,
     return pos
 
 
-@functools.partial(jax.jit, static_argnames=("depth",), donate_argnums=(0,))
 def forest_apply_ref(F_init: jax.Array, codes: jax.Array, feat: jax.Array,
                      thr: jax.Array, left: jax.Array, right: jax.Array,
                      leaf: jax.Array, out_col: jax.Array,
@@ -135,18 +150,26 @@ def forest_apply_ref(F_init: jax.Array, codes: jax.Array, feat: jax.Array,
       out_col: (T,) int32 starting output column of each tree's leaf block
                (0 for full-width trees, the output index for one-vs-all).
     Returns:
-      (n, d) float32 ``F_init + lr * sum_t tree_t(codes)``, accumulated
-      tree-by-tree in scan order — bit-identical to `tree.predict_forest`
-      for heap-canonicalized full-width trees and to the Pallas kernel's
-      grid order.
+      (n, d) float32 ``F_init + sum_t (lr * leaf_t)[walk_t(codes)]``,
+      accumulated tree-by-tree in scan order — bit-identical to
+      `tree.predict_forest` for heap-canonicalized full-width trees and to
+      the Pallas kernel's grid order.
     """
+    return _tree_sum_ref(F_init, codes, feat, thr, left, right,
+                         scaled_leaves(lr, leaf), out_col, depth=depth)
+
+
+@functools.partial(jax.jit, static_argnames=("depth",), donate_argnums=(0,))
+def _tree_sum_ref(F_init, codes, feat, thr, left, right, leaf_s, out_col, *,
+                  depth: int):
     n = codes.shape[0]
-    w = leaf.shape[2]
+    w = leaf_s.shape[2]
 
     def body(acc, tree_arrays):
         f, th, lft, rgt, v, col = tree_arrays
-        pos = node_walk_ref(f, th, lft, rgt, codes, depth=depth)
-        contrib = lr * v[pos]                              # (n, w)
+        pos = node_walk_ref(f, th.astype(jnp.int32), lft, rgt, codes,
+                            depth=depth)
+        contrib = v[pos]                                   # (n, w)
         if w == acc.shape[1]:          # full-width leaf block: col is 0
             acc = acc + contrib
         else:                          # narrow block at a traced column
@@ -155,12 +178,11 @@ def forest_apply_ref(F_init: jax.Array, codes: jax.Array, feat: jax.Array,
         return acc, None
 
     acc, _ = jax.lax.scan(body, F_init.astype(jnp.float32),
-                          (feat, thr, left, right, leaf,
+                          (feat, thr, left, right, leaf_s,
                            out_col.astype(jnp.int32)))
     return acc
 
 
-@functools.partial(jax.jit, static_argnames=("depth",), donate_argnums=(0,))
 def forest_apply_quant_ref(F_init: jax.Array, codes: jax.Array,
                            feat: jax.Array, thr: jax.Array, left: jax.Array,
                            right: jax.Array, leaf: jax.Array,
@@ -172,33 +194,15 @@ def forest_apply_quant_ref(F_init: jax.Array, codes: jax.Array,
     be uint8 (bin codes — widened to int32 for the walk, so split decisions
     are bit-identical to the fp32 forest), ``leaf`` is int8 or bfloat16 with
     a per-tree fp32 ``leaf_scale`` (T, 1); the dequantized value is
-    ``leaf.astype(f32) * scale`` and accumulation stays fp32.  Dequantizing
-    after the terminal gather is the same elementwise op as dequantizing the
-    whole block first, so this oracle is bit-identical to `forest_apply_ref`
-    on `core.quantize.dequantize_forest` of the same model — the exactness
+    ``leaf.astype(f32) * scale`` and accumulation stays fp32.  The block is
+    dequantized before the terminal gather (an exact selection), so this
+    oracle is bit-identical to `forest_apply_ref` on
+    `core.quantize.dequantize_forest` of the same model — the exactness
     contract the serving-tier tests assert.
     """
-    n = codes.shape[0]
-    w = leaf.shape[2]
-
-    def body(acc, tree_arrays):
-        f, th, lft, rgt, v, sc, col = tree_arrays
-        pos = node_walk_ref(f, th.astype(jnp.int32), lft, rgt, codes,
-                            depth=depth)
-        deq = v[pos].astype(jnp.float32) * sc[0]           # (n, w) fp32
-        contrib = lr * deq
-        if w == acc.shape[1]:          # full-width leaf block: col is 0
-            acc = acc + contrib
-        else:                          # narrow block at a traced column
-            cur = jax.lax.dynamic_slice(acc, (0, col), (n, w))
-            acc = jax.lax.dynamic_update_slice(acc, cur + contrib, (0, col))
-        return acc, None
-
-    acc, _ = jax.lax.scan(body, F_init.astype(jnp.float32),
-                          (feat, thr, left, right, leaf,
-                           leaf_scale.astype(jnp.float32),
-                           out_col.astype(jnp.int32)))
-    return acc
+    deq = leaf.astype(jnp.float32) * leaf_scale.astype(jnp.float32)[:, :, None]
+    return forest_apply_ref(F_init, codes, feat, thr, left, right, deq,
+                            out_col, lr, depth=depth)
 
 
 # ---------------------------------------------------------------------------
@@ -299,7 +303,7 @@ def _path_contribs(codes_i: jax.Array, sf, lo, hi, z) -> jax.Array:
                       for s in range(depth)], axis=-1)     # (n, L, D)
 
 
-def _scatter_contribs(acc, contrib, sf, leaf_v, col, lr):
+def _scatter_contribs(acc, contrib, sf, leaf_s, col):
     """Fold per-slot contributions into the (n, m, d) attribution tensor.
 
     Slot -> feature is an exact one-hot selection (unique features per path,
@@ -311,22 +315,27 @@ def _scatter_contribs(acc, contrib, sf, leaf_v, col, lr):
     tests document both regimes).
     """
     n, m_feats, d = acc.shape
-    L, w = leaf_v.shape
+    L, w = leaf_s.shape
     f1h = (sf[..., None] == jnp.arange(m_feats, dtype=jnp.int32)
            ).astype(jnp.float32)                           # (L, D, m)
-    A = jnp.einsum("nls,lsf->nlf", contrib, f1h)           # exact selection
+    # HIGHEST, as in the kernel: a TPU's default f32 matmul is one bf16 pass.
+    A = jnp.einsum("nls,lsf->nlf", contrib, f1h,
+                   precision=jax.lax.Precision.HIGHEST)    # exact selection
     At = A.transpose(0, 2, 1).reshape(n * m_feats, L)
-    res = jax.lax.dot_general(At, leaf_v,
+    # The kernel contracts a lane-padded leaf block (>= 8 columns): pad a
+    # narrow block the same way, since a matrix-vector product may sum the
+    # leaf axis in another order than a matrix-matrix one.
+    res = jax.lax.dot_general(At, jnp.pad(leaf_s, ((0, 0), (0, (-w) % 8))),
                               dimension_numbers=(((1,), (0,)), ((), ())),
+                              precision=jax.lax.Precision.HIGHEST,
                               preferred_element_type=jnp.float32)
-    res = res.reshape(n, m_feats, w)
+    res = res[:, :w].reshape(n, m_feats, w)
     if w == d:                                 # full-width leaf block: col 0
-        return acc + lr * res
+        return acc + res
     cur = jax.lax.dynamic_slice(acc, (0, 0, col), (n, m_feats, w))
-    return jax.lax.dynamic_update_slice(acc, cur + lr * res, (0, 0, col))
+    return jax.lax.dynamic_update_slice(acc, cur + res, (0, 0, col))
 
 
-@functools.partial(jax.jit, static_argnames=("depth",), donate_argnums=(0,))
 def tree_shap_ref(phi_init: jax.Array, codes: jax.Array, slot_feat: jax.Array,
                   slot_lo: jax.Array, slot_hi: jax.Array, slot_z: jax.Array,
                   leaf: jax.Array, out_col: jax.Array, lr: jax.Array, *,
@@ -348,20 +357,27 @@ def tree_shap_ref(phi_init: jax.Array, codes: jax.Array, slot_feat: jax.Array,
       summing over the feature axis and adding the expected value gives the
       raw ensemble prediction exactly (per tree, per path).
     """
+    return _tree_shap_sum_ref(phi_init, codes, slot_feat, slot_lo, slot_hi,
+                              slot_z, scaled_leaves(lr, leaf), out_col,
+                              depth=depth)
+
+
+@functools.partial(jax.jit, static_argnames=("depth",), donate_argnums=(0,))
+def _tree_shap_sum_ref(phi_init, codes, slot_feat, slot_lo, slot_hi, slot_z,
+                       leaf_s, out_col, *, depth: int):
     codes_i = codes.astype(jnp.int32)
 
     def body(acc, xs):
         sf, lo, hi, z, v, col = xs
         contrib = _path_contribs(codes_i, sf, lo, hi, z.astype(jnp.float32))
-        return _scatter_contribs(acc, contrib, sf, v, col, lr), None
+        return _scatter_contribs(acc, contrib, sf, v, col), None
 
     acc, _ = jax.lax.scan(body, phi_init.astype(jnp.float32),
-                          (slot_feat, slot_lo, slot_hi, slot_z, leaf,
+                          (slot_feat, slot_lo, slot_hi, slot_z, leaf_s,
                            out_col.astype(jnp.int32)))
     return acc
 
 
-@functools.partial(jax.jit, static_argnames=("depth",), donate_argnums=(0,))
 def tree_shap_interventional_ref(phi_init: jax.Array, codes: jax.Array,
                                  bg_codes: jax.Array, slot_feat: jax.Array,
                                  slot_lo: jax.Array, slot_hi: jax.Array,
@@ -375,6 +391,15 @@ def tree_shap_interventional_ref(phi_init: jax.Array, codes: jax.Array,
     the ``(B, m)`` background rows — so ``sum(phi) = f(x) - mean_b f(b)``
     exactly and the matching base value is the mean background prediction.
     """
+    return _tree_shap_interventional_sum_ref(
+        phi_init, codes, bg_codes, slot_feat, slot_lo, slot_hi,
+        scaled_leaves(lr, leaf), out_col, depth=depth)
+
+
+@functools.partial(jax.jit, static_argnames=("depth",), donate_argnums=(0,))
+def _tree_shap_interventional_sum_ref(phi_init, codes, bg_codes, slot_feat,
+                                      slot_lo, slot_hi, leaf_s, out_col, *,
+                                      depth: int):
     codes_i = codes.astype(jnp.int32)
     bg_i = bg_codes.astype(jnp.int32)
     n_bg = bg_codes.shape[0]
@@ -396,10 +421,10 @@ def tree_shap_interventional_ref(phi_init: jax.Array, codes: jax.Array,
 
         csum, _ = jax.lax.scan(bg_body, jnp.zeros(o.shape, jnp.float32), ob)
         contrib = csum / jnp.float32(n_bg)
-        return _scatter_contribs(acc, contrib, sf, v, col, lr), None
+        return _scatter_contribs(acc, contrib, sf, v, col), None
 
     acc, _ = jax.lax.scan(body, phi_init.astype(jnp.float32),
-                          (slot_feat, slot_lo, slot_hi, leaf,
+                          (slot_feat, slot_lo, slot_hi, leaf_s,
                            out_col.astype(jnp.int32)))
     return acc
 

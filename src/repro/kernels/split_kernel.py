@@ -8,9 +8,10 @@ the best ``(feature, bin)`` split under the paper's eq. (4) score
     gain = 0.5 * (S(R_l) + S(R_r) - S(R_parent)).
 
 Grid = ``(n_nodes, m_tiles)``; each step loads one feature tile of one node's
-histogram, computes the cumulative left/right statistics along the bin axis on
-the VPU, scores every candidate threshold, and folds its local arg-max into the
-per-node output block.  The output block for a node is revisited across the
+histogram, computes the cumulative left/right statistics along the bin axis
+(one lower-triangular matmul per feature on the MXU — Mosaic has no cumsum),
+scores every candidate threshold on the VPU, and folds its local arg-max into
+the per-node output block.  The output block for a node is revisited across the
 sequential feature-tile axis — the canonical Pallas accumulation pattern
 (init at ``ft == 0``, strict ``>`` keeps the *first* maximum, matching
 ``jnp.argmax`` tie-breaking over the flattened ``(m, B)`` axis).
@@ -20,10 +21,10 @@ Channel layout: ``C`` is the lane-padded stats width; the real channels are
 padding channels are zero.  The squared norm of the gradient block is computed
 as ``sum_c s_c^2 - count^2`` so no lane slicing is needed inside the kernel.
 
-VMEM working set per step: hist tile (MT x B x C x 4B) + its cumulative sum +
-a few (MT x B) score planes — with the default MT=8, B=256, C=128 that is
-~2 x 1 MB + 0.5 MB, comfortably inside 16 MB VMEM; the contraction-free body
-runs entirely on the VPU (8 x 128 lanes, C on the lane axis).
+VMEM working set per step: hist tile (MT x B x C x 4B, double-buffered) +
+the (B x B) triangle + one feature's (B x C) prefix sums and (B x 1) score
+columns — with the default MT=8, B=256, C=128 that is ~2 x 1 MB + 0.5 MB,
+comfortably inside 16 MB VMEM.
 """
 from __future__ import annotations
 
@@ -42,50 +43,63 @@ def _split_scan_kernel(params_ref, mask_ref, hist_ref, gain_ref, idx_ref, *,
     ft = pl.program_id(1)
     lam = params_ref[0, 0]
     min_data = params_ref[0, 1]
+    c_pad = hist_ref.shape[2]
 
-    hist = hist_ref[...]                                   # (MT, B, C)
-    c_pad = hist.shape[2]
-    csum = jnp.cumsum(hist, axis=1)                        # left stats for thr=b
+    # Prefix sums along the bin (sublane) axis as one lower-triangular
+    # matmul per feature: Mosaic has no cumsum, and HIGHEST keeps the fp32
+    # sums from being truncated to a single bf16 MXU pass.
+    tri = (jax.lax.broadcasted_iota(jnp.int32, (n_bins, n_bins), 0)
+           >= jax.lax.broadcasted_iota(jnp.int32, (n_bins, n_bins), 1)
+           ).astype(jnp.float32)
     # One-hot lane mask of the count channel (padding lanes are all-zero).
-    chan = jax.lax.broadcasted_iota(jnp.int32, (1, 1, c_pad), 2)
-    cvec = (chan == n_channels - 1).astype(jnp.float32)
+    cvec = (jax.lax.broadcasted_iota(jnp.int32, (1, c_pad), 1)
+            == n_channels - 1).astype(jnp.float32)
+    bins = jax.lax.broadcasted_iota(jnp.int32, (n_bins, 1), 0)
+    big = jnp.int32(m_tile * n_bins)
 
-    cl = jnp.sum(csum * cvec, axis=2)                      # (MT, B) left counts
-    sl_num = jnp.sum(csum * csum, axis=2) - cl * cl        # ||G_l||^2
-    totals = csum[:, n_bins - 1, :]                        # (MT, C) node totals
-    ct = jnp.sum(totals * cvec[0], axis=1)                 # (MT,) node counts
-    tot_num = jnp.sum(totals * totals, axis=1) - ct * ct
-    rdiff = totals[:, None, :] - csum                      # right stats
-    cr = ct[:, None] - cl
-    sr_num = jnp.sum(rdiff * rdiff, axis=2) - cr * cr
+    best_g = jnp.full((1, 1), NEG_INF, jnp.float32)
+    best_i = jnp.zeros((1, 1), jnp.int32)
+    for f in range(m_tile):
+        csum = jax.lax.dot_general(                        # (B, C) left stats
+            tri, hist_ref[f], dimension_numbers=(((1,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)
+        cl = jnp.sum(csum * cvec, axis=1, keepdims=True)   # (B, 1) counts
+        sl_num = jnp.sum(csum * csum, axis=1, keepdims=True) - cl * cl
+        totals = csum[n_bins - 1:n_bins, :]                # (1, C) node total
+        ct = jnp.sum(totals * cvec, axis=1, keepdims=True)  # (1, 1)
+        tot_num = jnp.sum(totals * totals, axis=1, keepdims=True) - ct * ct
+        rdiff = totals - csum                              # right stats
+        cr = ct - cl
+        sr_num = jnp.sum(rdiff * rdiff, axis=1, keepdims=True) - cr * cr
 
-    s_left = sl_num / (cl + lam)
-    s_right = sr_num / (cr + lam)
-    s_parent = tot_num / (ct + lam)
-    gain = 0.5 * (s_left + s_right - s_parent[:, None])    # (MT, B)
+        s_left = sl_num / (cl + lam)
+        s_right = sr_num / (cr + lam)
+        s_parent = tot_num / (ct + lam)
+        gain = 0.5 * (s_left + s_right - s_parent)         # (B, 1)
+        legal = (bins < n_bins - 1) & (cl >= min_data) & (cr >= min_data)
+        legal &= mask_ref[f:f + 1, :] > 0.0                # (1, 1) broadcast
+        gain = jnp.where(legal, gain, NEG_INF)
 
-    bins = jax.lax.broadcasted_iota(jnp.int32, (m_tile, n_bins), 1)
-    legal = (bins < n_bins - 1) & (cl >= min_data) & (cr >= min_data)
-    legal &= mask_ref[...] > 0.0                           # (MT, 1) broadcast
-    gain = jnp.where(legal, gain, NEG_INF)
-
-    flat = gain.reshape(1, m_tile * n_bins)
-    local_gain = jnp.max(flat)
-    local_idx = jnp.argmax(flat, axis=1)[0].astype(jnp.int32)
-    global_idx = ft * (m_tile * n_bins) + local_idx        # flat (feat, bin)
+        # First maximum of this feature (jnp.argmax tie-breaking); features
+        # are visited in index order, so strict ``>`` keeps the first one.
+        g_f = jnp.max(gain, axis=0, keepdims=True)         # (1, 1)
+        i_f = jnp.min(jnp.where(gain == g_f, bins + f * n_bins, big),
+                      axis=0, keepdims=True)
+        better = g_f > best_g
+        best_g = jnp.where(better, g_f, best_g)
+        best_i = jnp.where(better, i_f, best_i)
+    best_i = best_i + ft * (m_tile * n_bins)               # flat (feat, bin)
 
     @pl.when(ft == 0)
     def _init():
         gain_ref[...] = jnp.full(gain_ref.shape, NEG_INF, jnp.float32)
         idx_ref[...] = jnp.zeros(idx_ref.shape, jnp.int32)
 
-    cur_gain = gain_ref[...][0, 0]
-    cur_idx = idx_ref[...][0, 0]
-    better = local_gain > cur_gain
-    gain_ref[...] = jnp.broadcast_to(jnp.where(better, local_gain, cur_gain),
-                                     gain_ref.shape)
-    idx_ref[...] = jnp.broadcast_to(jnp.where(better, global_idx, cur_idx),
-                                    idx_ref.shape)
+    cur_g = gain_ref[...]                                  # (1, lane_pad)
+    better = best_g > cur_g
+    gain_ref[...] = jnp.where(better, best_g, cur_g)
+    idx_ref[...] = jnp.where(better, best_i, idx_ref[...])
 
 
 @functools.partial(
@@ -95,7 +109,7 @@ def _split_scan_kernel(params_ref, mask_ref, hist_ref, gain_ref, idx_ref, *,
 def split_scan_pallas(hist: jax.Array, params: jax.Array, mask: jax.Array, *,
                       n_nodes: int, n_bins: int, n_channels: int,
                       m_tile: int = 8, lane_pad: int = 8,
-                      interpret: bool = True):
+                      interpret: bool):
     """Raw kernel entry (padded inputs required — use `ops.split_scan`).
 
     Args:
@@ -112,7 +126,7 @@ def split_scan_pallas(hist: jax.Array, params: jax.Array, mask: jax.Array, *,
     assert m_pad % m_tile == 0 and nb_total == n_nodes * n_bins
     grid = (n_nodes, m_pad // m_tile)
 
-    return pl.pallas_call(
+    gain, idx = pl.pallas_call(
         functools.partial(_split_scan_kernel, n_bins=n_bins,
                           n_channels=n_channels, m_tile=m_tile),
         grid=grid,
@@ -121,13 +135,16 @@ def split_scan_pallas(hist: jax.Array, params: jax.Array, mask: jax.Array, *,
             pl.BlockSpec((m_tile, 1), lambda node, ft: (ft, 0)),
             pl.BlockSpec((m_tile, n_bins, c), lambda node, ft: (ft, node, 0)),
         ],
+        # A unit middle axis makes the per-node (1, lane_pad) block legal
+        # under Mosaic's (8, 128) tiling rule.
         out_specs=[
-            pl.BlockSpec((1, lane_pad), lambda node, ft: (node, 0)),
-            pl.BlockSpec((1, lane_pad), lambda node, ft: (node, 0)),
+            pl.BlockSpec((None, 1, lane_pad), lambda node, ft: (node, 0, 0)),
+            pl.BlockSpec((None, 1, lane_pad), lambda node, ft: (node, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((n_nodes, lane_pad), jnp.float32),
-            jax.ShapeDtypeStruct((n_nodes, lane_pad), jnp.int32),
+            jax.ShapeDtypeStruct((n_nodes, 1, lane_pad), jnp.float32),
+            jax.ShapeDtypeStruct((n_nodes, 1, lane_pad), jnp.int32),
         ],
         interpret=interpret,
     )(params, mask, hist)
+    return gain[:, 0], idx[:, 0]

@@ -7,24 +7,18 @@ from typing import Optional, Tuple
 import jax
 
 
-def _axis_type_kwargs(n_axes: int) -> dict:
-    # jax >= 0.5 takes explicit axis types; older releases have no AxisType.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 single pod (256 chips) or 2x16x16 multi-pod (512 chips)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
     """Arbitrary mesh (tests / elastic re-mesh use small shapes)."""
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def device_subset_mesh(n_devices: int, model_parallel: int = 1,

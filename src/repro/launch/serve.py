@@ -140,6 +140,8 @@ def main():
                     help="write the --chaos stats artifact (JSON) here")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     if args.demo:
         _train_demo(args.ckpt, args.seed)

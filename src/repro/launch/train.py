@@ -24,6 +24,7 @@ from repro.configs import ARCH_NAMES, get_config, smoke_config
 from repro.data import pipeline as data
 from repro.launch.mesh import host_device_mesh
 from repro.models import lm
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.runtime.fault import RestartableLoop
 from repro.training import optimizer as opt
 from repro.training import train_lib
@@ -231,6 +232,7 @@ def main():
                          "hosts on CPU, export XLA_FLAGS=--xla_force_host_"
                          "platform_device_count=8 before launching")
     args = ap.parse_args()
+    enable_compile_cache()
 
     res = (train_gbdt(args) if args.arch == "sketchboost-gbdt"
            else train_lm(args))

@@ -196,20 +196,13 @@ def extrapolate(v1: float, v2: float, l1: int, l2: int, l_full: int) -> float:
     return max(intercept + slope * l_full, 0.0)
 
 
-def _cost_dict(cost) -> Dict:
-    # jaxlib < 0.5 wraps Compiled.cost_analysis() in a one-element list.
-    if isinstance(cost, (list, tuple)):
-        return cost[0] if cost else {}
-    return cost
-
-
 def cost_flops(cost: Dict) -> float:
-    return float(_cost_dict(cost).get("flops", 0.0))
+    return float(cost.get("flops", 0.0))
 
 
 def cost_bytes(cost: Dict) -> float:
     """Total bytes accessed from a cost_analysis dict ('bytes accessed')."""
-    return float(_cost_dict(cost).get("bytes accessed", 0.0))
+    return float(cost.get("bytes accessed", 0.0))
 
 
 def model_flops_train(n_params: int, tokens: int) -> float:

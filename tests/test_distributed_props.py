@@ -24,7 +24,7 @@ from hypothesis import strategies as st
 
 jax = pytest.importorskip("jax")
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import distributed as GD

@@ -124,7 +124,7 @@ def test_sketched_psum_with_error_feedback_converges():
     """On a 1-device axis, sketched psum + error feedback must reconstruct the
     gradient on average: feeding the same gradient repeatedly with error
     feedback accumulates to the true direction."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.distributed.compression import sketched_psum
     from repro.launch.mesh import make_mesh
@@ -139,7 +139,7 @@ def test_sketched_psum_with_error_feedback_converges():
     f = jax.jit(shard_map(run, mesh=mesh,
                           in_specs=({"w": P()}, {"w": P()}, P()),
                           out_specs=({"w": P()}, {"w": P()}),
-                          check_rep=False))
+                          check_vma=False))
     acc = jnp.zeros_like(g["w"])
     res = {"w": jnp.zeros_like(g["w"])}
     for i in range(64):

@@ -159,7 +159,7 @@ def test_top_outputs_error_bound(rng):
 # ---------------------------------------------------------------------------
 
 def test_sketch_sharded_matches_single_device(rng):
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.launch.mesh import make_mesh
 
@@ -175,7 +175,7 @@ def test_sketch_sharded_matches_single_device(rng):
         out = jax.jit(shard_map(local, mesh=mesh,
                                 in_specs=(P("data", "model"),),
                                 out_specs=P("data", None),
-                                check_rep=False))(G)
+                                check_vma=False))(G)
         ref = SK.build_sketch(G, method=method, k=3, key=key)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=1e-5, atol=1e-5)
