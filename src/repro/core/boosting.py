@@ -42,6 +42,7 @@ from repro.core import losses as L
 from repro.core import quantize as Q
 from repro.core import sketch as SK
 from repro.core import tree as T
+from repro.runtime import tracing as TR
 
 
 @dataclasses.dataclass(frozen=True)
@@ -388,12 +389,13 @@ def _boost_round(F: jax.Array, codes: jax.Array, Y: jax.Array, key: jax.Array,
     `boost_scan` (whole-segment jit).
     """
     loss = L.get_loss(cfg.loss)
-    G, Hd = loss.grad_hess(F, Y)
-    G, Hd, bad = GU.guard_grad_hess(G, Hd, cfg.guard_policy, cfg.guard_clip,
-                                    cfg.hessian_floor)
-    k_key, s_key, c_key = jax.random.split(key, 3)
-    w = _sample_weights(s_key, G, cfg)
-    fmask = _feature_mask(c_key, codes.shape[1], cfg)
+    with jax.named_scope(TR.GRAD):
+        G, Hd = loss.grad_hess(F, Y)
+        G, Hd, bad = GU.guard_grad_hess(G, Hd, cfg.guard_policy,
+                                        cfg.guard_clip, cfg.hessian_floor)
+        k_key, s_key, c_key = jax.random.split(key, 3)
+        w = _sample_weights(s_key, G, cfg)
+        fmask = _feature_mask(c_key, codes.shape[1], cfg)
 
     def grow(stats, G_t, H_t):
         """Growth-strategy dispatch: ``(tree, leaf_pos)`` for one tree."""
@@ -410,19 +412,22 @@ def _boost_round(F: jax.Array, codes: jax.Array, Y: jax.Array, key: jax.Array,
                            hist_dtype=cfg.hist_dtype, **kw)
 
     if cfg.strategy == "single_tree":
-        Gk = SK.build_sketch(G * w, method=cfg.sketch_method, k=cfg.sketch_k,
-                             key=k_key)
-        stats = jnp.concatenate([Gk, w], axis=1)
-        # Re-check after the sketch: a projection can overflow on its own
-        # (inf * finite, eigh on a degenerate Gram) even from finite G.
-        stats, bad = GU.guard_stats(stats, cfg.guard_policy, cfg.guard_clip,
-                                    bad)
+        with jax.named_scope(TR.SKETCH):
+            Gk = SK.build_sketch(G * w, method=cfg.sketch_method,
+                                 k=cfg.sketch_k, key=k_key)
+            stats = jnp.concatenate([Gk, w], axis=1)
+            # Re-check after the sketch: a projection can overflow on its
+            # own (inf * finite, eigh on a degenerate Gram) even from
+            # finite G.
+            stats, bad = GU.guard_stats(stats, cfg.guard_policy,
+                                        cfg.guard_clip, bad)
         tree, leaf_pos = grow(stats, G, Hd)
-        if cfg.guard_policy == "skip_round":
-            scale = GU.skip_scale(bad, cfg.guard_policy)
-            tree = tree._replace(value=tree.value * scale,
-                                 gain=tree.gain * scale)
-        F = F + cfg.learning_rate * tree.value[leaf_pos]
+        with jax.named_scope(TR.LEAF):
+            if cfg.guard_policy == "skip_round":
+                scale = GU.skip_scale(bad, cfg.guard_policy)
+                tree = tree._replace(value=tree.value * scale,
+                                     gain=tree.gain * scale)
+            F = F + cfg.learning_rate * tree.value[leaf_pos]
         return F, tree
 
     # one_vs_all: vmap a single-output grower over the d outputs.  Each output j
@@ -433,15 +438,16 @@ def _boost_round(F: jax.Array, codes: jax.Array, Y: jax.Array, key: jax.Array,
         return grow(stats, g_j[:, None], h_j[:, None])
 
     trees, poss = jax.vmap(grow_one, in_axes=(1, 1))(G, Hd)  # (d, ...) axes
-    if cfg.guard_policy == "skip_round":
-        # one_vs_all stats are plain (sanitized-)gradient sums — no sketch
-        # projection to re-check — so the grad/hess flag alone gates the
-        # round; zero every output's tree at once.
-        scale = GU.skip_scale(bad, cfg.guard_policy)
-        trees = trees._replace(value=trees.value * scale,
-                               gain=trees.gain * scale)
-    delta = jax.vmap(lambda v, pos: v[pos, 0])(trees.value, poss)  # (d, n)
-    F = F + cfg.learning_rate * delta.T
+    with jax.named_scope(TR.LEAF):
+        if cfg.guard_policy == "skip_round":
+            # one_vs_all stats are plain (sanitized-)gradient sums — no
+            # sketch projection to re-check — so the grad/hess flag alone
+            # gates the round; zero every output's tree at once.
+            scale = GU.skip_scale(bad, cfg.guard_policy)
+            trees = trees._replace(value=trees.value * scale,
+                                   gain=trees.gain * scale)
+        delta = jax.vmap(lambda v, pos: v[pos, 0])(trees.value, poss)
+        F = F + cfg.learning_rate * delta.T                   # delta: (d, n)
     # Fold the per-output axis into a tree whose value tensor is (d, L, 1);
     # `forest.pack_forest` later flattens the (T, d, ...) buffers into width-1
     # packed trees with per-tree output columns.
@@ -529,8 +535,9 @@ def boost_scan(F: jax.Array, codes: jax.Array, Y: jax.Array,
         key, sub = jax.random.split(key)
         F, tree = _boost_round(F, codes, Y, sub, cfg)
         if has_eval:
-            Fv = _apply_tree(tree, codes_v, Fv, cfg)
-            vloss = loss.value(Fv, Yv).astype(jnp.float32)
+            with jax.named_scope(TR.EVAL):
+                Fv = _apply_tree(tree, codes_v, Fv, cfg)
+                vloss = loss.value(Fv, Yv).astype(jnp.float32)
         else:
             vloss = jnp.float32(0.0)
         return (F, Fv, key), (tree, vloss)
@@ -609,14 +616,23 @@ class SketchBoost:
         checkpoints every ``save_every`` round boundaries into
         ``cfg.ckpt_dir``; ``cfg.resume_from`` restores such a checkpoint and
         continues the run bit-identically (same data, same config).
+
+        Under a profiler the fit shows as host spans ``sketchboost.fit``
+        and its parts (`runtime.tracing`).
         """
-        if check_input:
-            X = validate_features(X, where="X")
-            y = validate_targets(y, loss=self.cfg.loss, n_rows=X.shape[0])
-        else:
-            X = np.asarray(X, np.float32)
-        d = self._infer_d(y)
-        cfg = self.cfg.resolve(d)
+        with TR.span(TR.FIT):
+            return self._fit(X, y, eval_set, verbose, check_input, chaos)
+
+    def _fit(self, X, y, eval_set, verbose, check_input, chaos):
+        with TR.span(TR.VALIDATE):
+            if check_input:
+                X = validate_features(X, where="X")
+                y = validate_targets(y, loss=self.cfg.loss,
+                                     n_rows=X.shape[0])
+            else:
+                X = np.asarray(X, np.float32)
+            d = self._infer_d(y)
+            cfg = self.cfg.resolve(d)
         chaos = _as_chaos_list(chaos)
 
         state = None
@@ -635,50 +651,55 @@ class SketchBoost:
             self.quantizer = state.quantizer
             self.base_score = jnp.asarray(state.packed.base, jnp.float32)
         else:
-            self.quantizer = Q.fit_quantizer(X, cfg.n_bins, seed=cfg.seed)
-        codes = self._bin(X, check_input=False)
-        Y = self._targets(y, d)
-        if state is None:
-            self.base_score = self._base(Y, d).astype(jnp.float32)
-
-        n = codes.shape[0]
-        if state is not None:
-            if tuple(state.F.shape) != (n, d):
+            with TR.span(TR.QUANTILES):
+                self.quantizer = Q.fit_quantizer(X, cfg.n_bins, seed=cfg.seed)
+        with TR.span(TR.BIN):
+            codes = self._bin(X, check_input=False)
+            Y = self._targets(y, d)
+            n = codes.shape[0]
+            if state is None:
+                self.base_score = self._base(Y, d).astype(jnp.float32)
+                F = jnp.broadcast_to(self.base_score,
+                                     (n, d)).astype(jnp.float32)
+            elif tuple(state.F.shape) != (n, d):
                 raise ValueError(
                     f"resume_from checkpoint holds training scores of shape "
                     f"{tuple(state.F.shape)} but X/y give ({n}, {d}); "
                     "resume must rerun fit() on the same training data")
-            F = jnp.asarray(state.F, jnp.float32)
-        else:
-            F = jnp.broadcast_to(self.base_score, (n, d)).astype(jnp.float32)
+            else:
+                F = jnp.asarray(state.F, jnp.float32)
         has_eval = eval_set is not None
         if has_eval:
-            Xv = (validate_features(eval_set[0],
-                                    n_features=self.quantizer.edges.shape[0],
-                                    where="eval_set X")
-                  if check_input else np.asarray(eval_set[0], np.float32))
-            codes_v = self._bin(Xv, check_input=False)
-            yv = (validate_targets(eval_set[1], loss=cfg.loss,
-                                   n_rows=codes_v.shape[0], where="eval_set y")
-                  if check_input else eval_set[1])
-            Yv = self._targets(yv, d)
-            if state is not None:
-                if state.Fv is None:
+            with TR.span(TR.VALIDATE):
+                Xv = (validate_features(
+                          eval_set[0],
+                          n_features=self.quantizer.edges.shape[0],
+                          where="eval_set X")
+                      if check_input else np.asarray(eval_set[0], np.float32))
+                yv = (validate_targets(eval_set[1], loss=cfg.loss,
+                                       n_rows=Xv.shape[0],
+                                       where="eval_set y")
+                      if check_input else eval_set[1])
+            with TR.span(TR.BIN):
+                codes_v = self._bin(Xv, check_input=False)
+                Yv = self._targets(yv, d)
+                nv = codes_v.shape[0]
+                if state is None:
+                    Fv = jnp.broadcast_to(self.base_score,
+                                          (nv, d)).astype(jnp.float32)
+                elif state.Fv is None:
                     raise ValueError(
                         "resume_from checkpoint was saved without an eval "
                         "set but fit() got one; the early-stopping "
                         "trajectory cannot be reconstructed — drop eval_set "
                         "or refit from scratch")
-                if tuple(state.Fv.shape) != (codes_v.shape[0], d):
+                elif tuple(state.Fv.shape) != (nv, d):
                     raise ValueError(
                         f"resume_from checkpoint holds eval scores of shape "
                         f"{tuple(state.Fv.shape)} but eval_set gives "
-                        f"({codes_v.shape[0]}, {d}); resume must use the "
-                        "same eval set")
-                Fv = jnp.asarray(state.Fv, jnp.float32)
-            else:
-                Fv = jnp.broadcast_to(
-                    self.base_score, (codes_v.shape[0], d)).astype(jnp.float32)
+                        f"({nv}, {d}); resume must use the same eval set")
+                else:
+                    Fv = jnp.asarray(state.Fv, jnp.float32)
         else:
             if state is not None and state.Fv is not None:
                 raise ValueError(
@@ -719,10 +740,11 @@ class SketchBoost:
             raise ValueError(f"unknown loop {cfg.loop!r}; "
                              "expected 'scan' or 'python'")
         self.cfg = cfg
-        self.packed = FO.pack_forest(
-            self.forest, self.base_score, cfg.learning_rate,
-            strategy=cfg.strategy,
-            max_depth=cfg.depth if cfg.growth == "leafwise" else None)
+        with TR.span(TR.PACK):
+            self.packed = FO.pack_forest(
+                self.forest, self.base_score, cfg.learning_rate,
+                strategy=cfg.strategy,
+                max_depth=cfg.depth if cfg.growth == "leafwise" else None)
         self._path_pack = None              # path slots belong to old forest
         return self
 
@@ -762,7 +784,12 @@ class SketchBoost:
         early-stopping replay between segments (see module docstring).
         Segments are additionally capped at checkpoint (``save_every``) and
         chaos-trigger boundaries so saves and injections land on exact round
-        boundaries; ``start``/``prefix``/``best`` seed a resumed run."""
+        boundaries; ``start``/``prefix``/``best`` seed a resumed run.
+
+        Every round of a segment records the host time at which the
+        segment's results reached the host as its ``train_time_s``: the
+        device is not interrupted inside a segment, so no finer time is
+        measured (a segment's rounds share one time)."""
         n_total = cfg.n_trees
         chunk = cfg.scan_chunk if cfg.scan_chunk > 0 else n_total
         chunk = max(1, min(chunk, max(n_total - start, 1)))
@@ -770,7 +797,6 @@ class SketchBoost:
         chunks = ([] if prefix is None else [prefix])
         done, stop = start, False
         t0 = time.perf_counter()
-        seg_start = 0.0
         while done < n_total and not stop:
             _chaos_check(chaos, done)
             Y = _chaos_mutate(chaos, Y, done)
@@ -781,50 +807,53 @@ class SketchBoost:
             nxt = _next_chaos_round(chaos, done)
             if nxt is not None:
                 steps = min(steps, nxt - done)
-            F, Fv, key, trees, vloss = boost_scan(
-                F, codes, Y, Fv, codes_v, Yv, key, cfg=cfg, n_steps=steps,
-                has_eval=has_eval)
-            vl = np.asarray(vloss)            # host sync = segment boundary
-            if cfg.guard_policy == "raise":
-                GU.check_scores_host(F, done + steps - 1)
+            with TR.span(TR.SEGMENT):
+                F, Fv, key, trees, vloss = boost_scan(
+                    F, codes, Y, Fv, codes_v, Yv, key, cfg=cfg,
+                    n_steps=steps, has_eval=has_eval)
+            with TR.span(TR.SYNC):
+                vl = np.asarray(vloss)        # host sync = segment boundary
             elapsed = time.perf_counter() - t0
-            keep = steps
-            for j in range(steps):
-                it = done + j
-                # Per-round timestamps are linearly interpolated within the
-                # segment (the device is not interrupted to timestamp trees).
-                t_j = seg_start + (elapsed - seg_start) * (j + 1) / steps
-                rec = {"round": it, "train_time_s": t_j}
-                if has_eval and it % cfg.eval_every == 0:
-                    v = float(vl[j])
-                    rec["valid_loss"] = v
-                    if v < best_loss - 1e-9:
-                        best_loss, best_round = v, it
-                    if (cfg.early_stopping_rounds
-                            and it - best_round >= cfg.early_stopping_rounds):
-                        self.history.append(rec)
-                        keep, stop = j + 1, True
-                        if verbose:
-                            print(f"[sketchboost] early stop @ {it} "
-                                  f"(best {best_loss:.5f} @ {best_round})")
-                        break
-                self.history.append(rec)
-            chunks.append(jax.tree.map(lambda x: x[:keep], trees))
-            done += keep
-            seg_start = elapsed
-            if (saver is not None and not stop and done % save_every == 0):
-                saver(done, _concat_chunks(chunks), F, Fv, key,
-                      best_loss, best_round, list(self.history))
+            with TR.span(TR.REPLAY):
+                if cfg.guard_policy == "raise":
+                    GU.check_scores_host(F, done + steps - 1)
+                keep = steps
+                for j in range(steps):
+                    it = done + j
+                    rec = {"round": it, "train_time_s": elapsed}
+                    if has_eval and it % cfg.eval_every == 0:
+                        v = float(vl[j])
+                        rec["valid_loss"] = v
+                        if v < best_loss - 1e-9:
+                            best_loss, best_round = v, it
+                        if (cfg.early_stopping_rounds
+                                and it - best_round
+                                >= cfg.early_stopping_rounds):
+                            self.history.append(rec)
+                            keep, stop = j + 1, True
+                            if verbose:
+                                print(f"[sketchboost] early stop @ {it} "
+                                      f"(best {best_loss:.5f} @ "
+                                      f"{best_round})")
+                            break
+                    self.history.append(rec)
+                chunks.append(jax.tree.map(lambda x: x[:keep], trees))
+                done += keep
+                if (saver is not None and not stop
+                        and done % save_every == 0):
+                    saver(done, _concat_chunks(chunks), F, Fv, key,
+                          best_loss, best_round, list(self.history))
             if verbose and not stop:
                 msg = f"[sketchboost] round {done - 1}"
                 if has_eval:
                     msg += f" valid_loss={float(vl[keep - 1]):.5f}"
                 print(msg)
 
-        stacked = _concat_chunks(chunks)
-        if best_round >= 0 and cfg.early_stopping_rounds:
-            keep_n = best_round + 1
-            stacked = jax.tree.map(lambda x: x[:keep_n], stacked)
+        with TR.span(TR.REPLAY):
+            stacked = _concat_chunks(chunks)
+            if best_round >= 0 and cfg.early_stopping_rounds:
+                keep_n = best_round + 1
+                stacked = jax.tree.map(lambda x: x[:keep_n], stacked)
         self.best_round = (best_round if best_round >= 0
                            else stacked.feat.shape[0] - 1)
         self.forest = _as_forest(stacked)

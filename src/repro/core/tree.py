@@ -20,6 +20,7 @@ import jax.numpy as jnp
 from repro.core import histogram as H
 from repro.core import split as S
 from repro.kernels import ref
+from repro.runtime import tracing as TR
 
 
 class Tree(NamedTuple):
@@ -103,48 +104,59 @@ def grow_tree(codes: jax.Array, stats: jax.Array, G: jax.Array, H_diag: jax.Arra
         if mode != "jnp":
             from repro.kernels import ops as kops
             interp = mode == "interpret"
-            if engine == "direct":
-                best_gain, best_idx = kops.histogram_splits(
-                    codes, node_pos, stats, lam, min_data, feature_mask,
-                    n_nodes=n_nodes, n_bins=n_bins, interpret=interp)
-            else:
-                best_gain, best_idx, prev_hist = kops.histogram_splits_level(
-                    codes, stats, state.order, state.counts, prev_hist,
-                    lam, min_data, feature_mask, n_nodes=n_nodes,
-                    n_bins=n_bins, subtract=subtract, hist_dtype=hist_dtype,
-                    interpret=interp)
-            sp = S.splits_from_flat(best_gain, best_idx, n_bins=n_bins,
-                                    min_gain=min_gain_)
+            with jax.named_scope(TR.HIST):
+                if engine == "direct":
+                    best_gain, best_idx = kops.histogram_splits(
+                        codes, node_pos, stats, lam, min_data, feature_mask,
+                        n_nodes=n_nodes, n_bins=n_bins, interpret=interp)
+                else:
+                    best_gain, best_idx, prev_hist = (
+                        kops.histogram_splits_level(
+                            codes, stats, state.order, state.counts,
+                            prev_hist, lam, min_data, feature_mask,
+                            n_nodes=n_nodes, n_bins=n_bins,
+                            subtract=subtract, hist_dtype=hist_dtype,
+                            interpret=interp))
+            with jax.named_scope(TR.ROUTE):
+                sp = S.splits_from_flat(best_gain, best_idx, n_bins=n_bins,
+                                        min_gain=min_gain_)
         else:
-            if engine == "direct":
-                hist = H.build_histograms_jnp(codes, node_pos, stats,
-                                              n_nodes=n_nodes, n_bins=n_bins)
-            else:
-                hist = H.build_level_jnp(codes, stats, state, prev_hist,
-                                         n_nodes=n_nodes, n_bins=n_bins,
-                                         subtract=subtract)
-                prev_hist = hist
-            gain = S.split_scores(hist, lam, min_data, feature_mask)
-            sp = S.best_splits(gain, min_gain_)
-        off = n_nodes - 1
-        heap_feat = jax.lax.dynamic_update_slice(heap_feat, sp.feat, (off,))
-        heap_thr = jax.lax.dynamic_update_slice(heap_thr, sp.thr, (off,))
-        heap_gain = jax.lax.dynamic_update_slice(heap_gain, sp.gain, (off,))
-        bits = route_bits(codes, node_pos, sp.feat, sp.thr)
-        node_pos = node_pos * 2 + bits
-        if state is not None and lvl < depth - 1:
-            state = H.advance_level_state(state, bits)
+            with jax.named_scope(TR.HIST):
+                if engine == "direct":
+                    hist = H.build_histograms_jnp(codes, node_pos, stats,
+                                                  n_nodes=n_nodes,
+                                                  n_bins=n_bins)
+                else:
+                    hist = H.build_level_jnp(codes, stats, state, prev_hist,
+                                             n_nodes=n_nodes, n_bins=n_bins,
+                                             subtract=subtract)
+                    prev_hist = hist
+                gain = S.split_scores(hist, lam, min_data, feature_mask)
+                sp = S.best_splits(gain, min_gain_)
+        with jax.named_scope(TR.ROUTE):
+            off = n_nodes - 1
+            heap_feat = jax.lax.dynamic_update_slice(heap_feat, sp.feat,
+                                                     (off,))
+            heap_thr = jax.lax.dynamic_update_slice(heap_thr, sp.thr, (off,))
+            heap_gain = jax.lax.dynamic_update_slice(heap_gain, sp.gain,
+                                                     (off,))
+            bits = route_bits(codes, node_pos, sp.feat, sp.thr)
+            node_pos = node_pos * 2 + bits
+            if state is not None and lvl < depth - 1:
+                state = H.advance_level_state(state, bits)
 
-    sample_w = stats[:, -1:]                              # SGB/GOSS weights
-    g_sum, h_sum = H.leaf_sums(node_pos, G * sample_w, H_diag * sample_w,
-                               n_leaves=2 ** depth)
-    value = -g_sum / (h_sum + lam)
-    # Per-leaf cover (weighted training row counts): the substrate for
-    # path-dependent TreeSHAP and cover/split-count importances — packed into
-    # the serving format by `forest.pack_forest` so explanation needs no
-    # re-scan of training data.
-    cover = jax.ops.segment_sum(sample_w[:, 0], node_pos.astype(jnp.int32),
-                                num_segments=2 ** depth)
+    with jax.named_scope(TR.LEAF):
+        sample_w = stats[:, -1:]                          # SGB/GOSS weights
+        g_sum, h_sum = H.leaf_sums(node_pos, G * sample_w, H_diag * sample_w,
+                                   n_leaves=2 ** depth)
+        value = -g_sum / (h_sum + lam)
+        # Per-leaf cover (weighted training row counts): the substrate for
+        # path-dependent TreeSHAP and cover/split-count importances — packed
+        # into the serving format by `forest.pack_forest` so explanation
+        # needs no re-scan of training data.
+        cover = jax.ops.segment_sum(sample_w[:, 0],
+                                    node_pos.astype(jnp.int32),
+                                    num_segments=2 ** depth)
     tree = Tree(feat=heap_feat, thr=heap_thr, value=value, gain=heap_gain,
                 cover=cover)
     return tree, node_pos
@@ -246,9 +258,11 @@ def grow_tree_leafwise(codes: jax.Array, stats: jax.Array, G: jax.Array,
             return jnp.concatenate([g, _psum(cnt)], axis=-1)
         return _psum(h)
 
+    @jax.named_scope(TR.HIST)
     def build_hist(rows, valid, key=None):
-        codes_g = codes[rows].astype(jnp.int32)
-        stats_g = stats[rows].astype(jnp.float32) * valid[:, None]
+        with jax.named_scope(TR.GATHER):
+            codes_g = codes[rows].astype(jnp.int32)
+            stats_g = stats[rows].astype(jnp.float32) * valid[:, None]
         if mode != "jnp":
             from repro.kernels import ops as kops
             h = kops.node_histogram(codes_g, stats_g, n_bins=n_bins,
@@ -258,6 +272,7 @@ def grow_tree_leafwise(codes: jax.Array, stats: jax.Array, G: jax.Array,
             h = H.node_hist_jnp(codes_g, stats_g, n_bins=n_bins)
         return reduce_hist(h, key)
 
+    @jax.named_scope(TR.HIST)
     def score(hists, k: int) -> S.Splits:
         """Best splits of ``k`` stacked (m, B, c) histograms."""
         if mode != "jnp":
@@ -304,8 +319,9 @@ def grow_tree_leafwise(codes: jax.Array, stats: jax.Array, G: jax.Array,
         do = g_p > min_gain_                      # -inf once exhausted
         f_p, t_p = s["pend_feat"][p], s["pend_thr"][p]
         c1, c2 = s["node_count"], s["node_count"] + 1
-        go_right = jnp.take(codes, f_p, axis=1).astype(jnp.int32) > t_p
-        part = H.split_partition_at(s["part"], p, c1, c2, go_right, do)
+        with jax.named_scope(TR.ROUTE):
+            go_right = jnp.take(codes, f_p, axis=1).astype(jnp.int32) > t_p
+            part = H.split_partition_at(s["part"], p, c1, c2, go_right, do)
 
         def upd(a, i, v):
             return a.at[i].set(jnp.where(do, v, a[i]))
@@ -330,9 +346,10 @@ def grow_tree_leafwise(codes: jax.Array, stats: jax.Array, G: jax.Array,
                    if dist_hist_compression == "sketch" else None)
         built = build_hist(rows, valid.astype(jnp.float32), exp_key)
         s_p = s["slot_of"][p]
-        sib = s["cache"][s_p] - built
-        hist_l = jnp.where(built_left, built, sib)
-        hist_r = jnp.where(built_left, sib, built)
+        with jax.named_scope(TR.HIST), jax.named_scope(TR.SUBTRACT):
+            sib = s["cache"][s_p] - built
+            hist_l = jnp.where(built_left, built, sib)
+            hist_r = jnp.where(built_left, sib, built)
         sp = score(jnp.stack([hist_l, hist_r]), 2)
 
         # Frontier update: children become pending unless illegal (no
@@ -367,27 +384,29 @@ def grow_tree_leafwise(codes: jax.Array, stats: jax.Array, G: jax.Array,
     # Terminal node of every row, then the exact leaf pass (eq. (3)) on the
     # full gradients — identical per-leaf summation order to the level-wise
     # grower (original row order within each leaf).
-    leaf_pos = jnp.zeros((n,), jnp.int32).at[part.order].set(part.node_perm)
-    sample_w = stats[:, -1:]
-    g_sum, h_sum = H.leaf_sums(leaf_pos, G * sample_w, H_diag * sample_w,
-                               n_leaves=N)
-    g_sum, h_sum = _psum(g_sum), _psum(h_sum)      # exact: never sketched
-    is_term = left == ids
-    value = jnp.where(is_term[:, None], -g_sum / (h_sum + lam_), 0.0)
+    with jax.named_scope(TR.LEAF):
+        leaf_pos = jnp.zeros((n,), jnp.int32).at[part.order].set(
+            part.node_perm)
+        sample_w = stats[:, -1:]
+        g_sum, h_sum = H.leaf_sums(leaf_pos, G * sample_w,
+                                   H_diag * sample_w, n_leaves=N)
+        g_sum, h_sum = _psum(g_sum), _psum(h_sum)  # exact: never sketched
+        is_term = left == ids
+        value = jnp.where(is_term[:, None], -g_sum / (h_sum + lam_), 0.0)
 
-    # Node covers bottom-up: children have larger ids, so one reverse sweep
-    # makes every internal cover the exact sum of its children (the
-    # invariant TreeSHAP's zero-fractions rely on).
-    cover_leaf = _psum(jax.ops.segment_sum(sample_w[:, 0],
-                                           leaf_pos.astype(jnp.int32),
-                                           num_segments=N))
+        # Node covers bottom-up: children have larger ids, so one reverse
+        # sweep makes every internal cover the exact sum of its children
+        # (the invariant TreeSHAP's zero-fractions rely on).
+        cover_leaf = _psum(jax.ops.segment_sum(sample_w[:, 0],
+                                               leaf_pos.astype(jnp.int32),
+                                               num_segments=N))
 
-    def up(i, cov):
-        j = N - 1 - i
-        summed = cov[left[j]] + cov[right[j]]
-        return cov.at[j].set(jnp.where(left[j] != j, summed, cov[j]))
+        def up(i, cov):
+            j = N - 1 - i
+            summed = cov[left[j]] + cov[right[j]]
+            return cov.at[j].set(jnp.where(left[j] != j, summed, cov[j]))
 
-    cover = jax.lax.fori_loop(0, N, up, cover_leaf)
+        cover = jax.lax.fori_loop(0, N, up, cover_leaf)
     tree = NodeTree(feat=carry["feat"], thr=carry["thr"], left=left,
                     right=right, value=value, gain=carry["gain"],
                     cover=cover, node_count=carry["node_count"])

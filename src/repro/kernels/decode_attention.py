@@ -81,6 +81,7 @@ def decode_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
                                block_s=block_s)
     out = pl.pallas_call(
         kernel,
+        name="decode_attention_pallas",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1,), lambda b, h, s: (b,)),

@@ -91,6 +91,7 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
                                kv_len=sk)
     return pl.pallas_call(
         kernel,
+        name="flash_attention_pallas",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, block_q, dh), lambda b, h, iq, ik: (b, h, iq, 0)),

@@ -90,6 +90,7 @@ def histogram_pallas(codes_t: jax.Array, node_pos: jax.Array, stats: jax.Array,
     # middle axis makes every (1, row_tile) code / node block legal.
     return pl.pallas_call(
         functools.partial(_hist_kernel, n_bins=n_bins, nb_chunk=nb_chunk),
+        name="histogram_pallas",
         grid=grid,
         in_specs=[
             pl.BlockSpec((None, 1, row_tile), lambda f, nb, t: (f, 0, t)),
@@ -162,6 +163,7 @@ def hist_tiles_pallas(codes_t: jax.Array, stats: jax.Array, *, n_bins: int,
     return pl.pallas_call(
         functools.partial(_hist_tiles_kernel, n_bins=n_bins,
                           compute_dtype=compute_dtype, precision=precision),
+        name="hist_tiles_pallas",
         grid=grid,
         in_specs=[
             pl.BlockSpec((None, 1, row_tile), lambda f, t: (f, 0, t)),

@@ -22,6 +22,7 @@ from repro.kernels.predict_kernel import (forest_traverse_pallas,
 from repro.kernels.ref import SHAP_BIG_BIN as _SHAP_BIG
 from repro.kernels.shap_kernel import shap_pallas
 from repro.kernels.split_kernel import split_scan_pallas
+from repro.runtime import tracing as TR
 
 
 def resolve_dispatch(use_kernel, interpret: bool | None = None):
@@ -227,46 +228,51 @@ def histogram_splits_level(codes: jax.Array, stats: jax.Array,
     c = stats.shape[1]
     lane_pad = _resolve_lane_pad(lane_pad, interpret)
     b_pad = n_bins + (-n_bins) % 8               # sublane-aligned bin axis
-    if subtract:
-        P = n_nodes // 2
-        side, is_built = smaller_children(counts)
-        build_counts = jnp.where(is_built, counts, 0)
-        n_eff = n // 2                           # smaller halves sum <= n/2
-    else:
-        build_counts = counts
-        n_eff = n
-    n_tiles = n_eff // row_tile + 1 + n_nodes
-    tile_node, src_perm, valid = _tile_plan(counts, build_counts, n=n,
-                                            n_tiles=n_tiles,
-                                            row_tile=row_tile)
-    ri = order[src_perm]
-    codes_g = codes[ri].astype(jnp.int32)                  # (S, m)
-    stats_g = stats.astype(jnp.float32)[ri] * valid[:, None]
-    stats_g = _pad_to(stats_g, lane_pad, axis=1)
-    tiles = hist_tiles_pallas(codes_g.T, stats_g, n_bins=b_pad,
+    with jax.named_scope(TR.GATHER):
+        if subtract:
+            P = n_nodes // 2
+            side, is_built = smaller_children(counts)
+            build_counts = jnp.where(is_built, counts, 0)
+            n_eff = n // 2                       # smaller halves sum <= n/2
+        else:
+            build_counts = counts
+            n_eff = n
+        n_tiles = n_eff // row_tile + 1 + n_nodes
+        tile_node, src_perm, valid = _tile_plan(counts, build_counts, n=n,
+                                                n_tiles=n_tiles,
+                                                row_tile=row_tile)
+        ri = order[src_perm]
+        codes_t = codes[ri].astype(jnp.int32).T              # (m, S)
+        stats_g = stats.astype(jnp.float32)[ri] * valid[:, None]
+        stats_g = _pad_to(stats_g, lane_pad, axis=1)
+    tiles = hist_tiles_pallas(codes_t, stats_g, n_bins=b_pad,
                               row_tile=row_tile, hist_dtype=hist_dtype,
                               interpret=interpret)
-    nodes4 = jax.ops.segment_sum(tiles.transpose(1, 0, 2, 3), tile_node,
-                                 num_segments=n_nodes,
-                                 indices_are_sorted=True)  # (nodes, m, Bp, C)
-    nodes4 = nodes4[:, :, :n_bins, :]
+    with jax.named_scope(TR.EPILOGUE):
+        nodes4 = jax.ops.segment_sum(tiles.transpose(1, 0, 2, 3), tile_node,
+                                     num_segments=n_nodes,
+                                     indices_are_sorted=True)
+        nodes4 = nodes4[:, :, :n_bins, :]                  # (nodes, m, B, C)
     if subtract:
-        prev4 = prev_hist.reshape(m, P, n_bins, -1).transpose(1, 0, 2, 3)
-        pairs = nodes4.reshape((P, 2) + nodes4.shape[1:])
-        built4 = jnp.take_along_axis(
-            pairs, side.reshape(P, 1, 1, 1, 1), axis=1)[:, 0]
-        nodes4 = interleave_children(side, built4, prev4 - built4)
-    hist_native = nodes4.transpose(1, 0, 2, 3).reshape(m, n_nodes * n_bins, -1)
-
-    mask = (jnp.ones((m,), jnp.float32) if feature_mask is None
-            else feature_mask.astype(jnp.float32))
-    hist_p = _pad_to(hist_native, m_tile, axis=0)
-    mask_p = _pad_to(mask, m_tile, axis=0)[:, None]
-    params = jnp.stack([jnp.float32(lam), jnp.float32(min_data)])[None, :]
+        with jax.named_scope(TR.SUBTRACT):
+            prev4 = prev_hist.reshape(m, P, n_bins, -1).transpose(1, 0, 2, 3)
+            pairs = nodes4.reshape((P, 2) + nodes4.shape[1:])
+            built4 = jnp.take_along_axis(
+                pairs, side.reshape(P, 1, 1, 1, 1), axis=1)[:, 0]
+            nodes4 = interleave_children(side, built4, prev4 - built4)
+    with jax.named_scope(TR.EPILOGUE):
+        hist_native = nodes4.transpose(1, 0, 2, 3).reshape(
+            m, n_nodes * n_bins, -1)
+        mask = (jnp.ones((m,), jnp.float32) if feature_mask is None
+                else feature_mask.astype(jnp.float32))
+        hist_p = _pad_to(hist_native, m_tile, axis=0)
+        mask_p = _pad_to(mask, m_tile, axis=0)[:, None]
+        params = jnp.stack([jnp.float32(lam), jnp.float32(min_data)])[None, :]
     gain, idx = split_scan_pallas(hist_p, params, mask_p, n_nodes=n_nodes,
                                   n_bins=n_bins, n_channels=c, m_tile=m_tile,
                                   lane_pad=lane_pad, interpret=interpret)
-    return gain[:, 0], idx[:, 0], hist_native
+    with jax.named_scope(TR.EPILOGUE):
+        return gain[:, 0], idx[:, 0], hist_native
 
 
 @functools.partial(jax.jit, static_argnames=("n_bins", "row_tile",

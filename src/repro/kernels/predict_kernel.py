@@ -143,7 +143,7 @@ def _forest_quant_kernel(col_ref, lr_ref, scale_ref, init_ref, codes_ref,
 
 
 def _traverse_call(kernel, out_col, lr_row, scale_rows, F_init, codes, feat,
-                   thr, left, right, leaf, *, row_tile: int,
+                   thr, left, right, leaf, *, name: str, row_tile: int,
                    interpret: bool):
     """Shared ``pallas_call`` of both traversal kernels.
 
@@ -173,7 +173,7 @@ def _traverse_call(kernel, out_col, lr_row, scale_rows, F_init, codes, feat,
         + [pl.BlockSpec((None, l_pad, w_pad), lambda r, t, col: (t, 0, 0))],
         out_specs=rows(d_pad))
     return pl.pallas_call(
-        kernel, grid_spec=grid_spec,
+        kernel, grid_spec=grid_spec, name=name,
         out_shape=jax.ShapeDtypeStruct((n_pad, d_pad), jnp.float32),
         interpret=interpret,
     )(out_col, lr_row, *scale, F_init, codes, node3(feat), node3(thr),
@@ -211,7 +211,8 @@ def forest_traverse_pallas(lr_row: jax.Array, out_col: jax.Array,
     kernel = functools.partial(_forest_kernel, depth=depth,
                                leaf_width=leaf_width)
     return _traverse_call(kernel, out_col, lr_row, None, F_init, codes, feat,
-                          thr, left, right, leaf, row_tile=row_tile,
+                          thr, left, right, leaf,
+                          name="forest_traverse_pallas", row_tile=row_tile,
                           interpret=interpret)
 
 
@@ -243,5 +244,6 @@ def forest_traverse_quant_pallas(lr_row: jax.Array, out_col: jax.Array,
     kernel = functools.partial(_forest_quant_kernel, depth=depth,
                                leaf_width=leaf_width)
     return _traverse_call(kernel, out_col, lr_row, scale_rows, F_init, codes,
-                          feat, thr, left, right, leaf, row_tile=row_tile,
-                          interpret=interpret)
+                          feat, thr, left, right, leaf,
+                          name="forest_traverse_quant_pallas",
+                          row_tile=row_tile, interpret=interpret)

@@ -155,6 +155,7 @@ def shap_pallas(lr_row: jax.Array, out_col: jax.Array, codes: jax.Array,
                                lambda r, t, col: (r, 0, 0)))
     return pl.pallas_call(
         functools.partial(_shap_kernel, depth=depth, leaf_width=leaf_width),
+        name="shap_pallas",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_pad, m_pad, d_pad), jnp.float32),
         interpret=interpret,

@@ -129,6 +129,7 @@ def split_scan_pallas(hist: jax.Array, params: jax.Array, mask: jax.Array, *,
     gain, idx = pl.pallas_call(
         functools.partial(_split_scan_kernel, n_bins=n_bins,
                           n_channels=n_channels, m_tile=m_tile),
+        name="split_scan_pallas",
         grid=grid,
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),

@@ -1,7 +1,7 @@
 """Where JAX's persistent compilation cache lives.
 
-Every entry point that compiles real work (`chip_smoke.py`,
-`benchmarks/run.py`, `launch/train.py`, `launch/serve.py`) calls
+Every entry point that compiles real work (`chip_smoke.py`, the chip
+benchmark in `bench/`, `launch/train.py`, `launch/serve.py`) calls
 `enable_compile_cache` before it touches a device.  The cache key includes
 the directory, so the directory must not move between runs: it is either
 the one ``JAX_COMPILATION_CACHE_DIR`` names or a fixed, git-ignored
