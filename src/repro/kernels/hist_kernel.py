@@ -25,14 +25,17 @@ node-contiguous tiles (each tile belongs to exactly ONE node; per-node row
 ranges are padded to the tile size), so the one-hot space per row tile is
 only ``n_bins`` wide:
 
-    tile_hist[f, t] = onehot(bin_f)^T @ stats_tile     (TN, B)^T  (TN, C)
+    node_hist[f, tile_node[t]] (+)= onehot(bin_f)^T @ stats_tile
+                                    (TN, B)^T         (TN, C)
 
-Grid = (features, tiles); every output block is written exactly once (no
-revisit/accumulation pattern), and a cheap jnp epilogue segment-sums tiles
-into their nodes — the per-tile node-range bookkeeping that replaces the
-in-kernel node axis.  Per-level FLOPs are O(n * m * c) regardless of depth.
-VMEM per step: onehot (TN x B x 4B) + stats (TN x C) + out (B x C) — ~0.5 MB
-at TN=256, B=256, C=128.
+Grid = (features, tiles), tiles innermost and sequential.  The tile->node
+map is scalar-prefetched into SMEM and drives the output index map, so the
+tiles of one node revisit that node's ``(B, C)`` block in one run: the
+node's first tile writes its dot, each later tile adds its dot to the
+block, and the block goes back to HBM once, when the run ends.  The kernel writes node
+histograms, never per-tile ones.  Per-level FLOPs are O(n * m * c)
+regardless of depth.  VMEM per step: onehot (TN x B x 4B) + stats (TN x C)
++ out (B x C) — ~0.5 MB at TN=256, B=256, C=128.
 """
 from __future__ import annotations
 
@@ -41,6 +44,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.runtime import tracing as TR
 
 
 def _hist_kernel(codes_ref, node_ref, stats_ref, out_ref, *, n_bins: int,
@@ -107,29 +113,40 @@ def histogram_pallas(codes_t: jax.Array, node_pos: jax.Array, stats: jax.Array,
 HIST_DTYPES = ("float32", "bfloat16")
 
 
-def _hist_tiles_kernel(codes_ref, stats_ref, out_ref, *, n_bins: int,
-                       compute_dtype, precision):
+def _hist_tiles_kernel(node_ref, codes_ref, stats_ref, out_ref, *,
+                       n_bins: int, compute_dtype, precision):
+    t = pl.program_id(1)
+    first = (t == 0) | (node_ref[t] != node_ref[jnp.maximum(t - 1, 0)])
     code = codes_ref[...].astype(jnp.int32)               # (1, TN)
     tn = code.shape[1]
     rows = jax.lax.broadcasted_iota(jnp.int32, (n_bins, tn), 0)
     onehot_t = (code == rows).astype(compute_dtype)       # (B, TN)
-    out_ref[...] = jax.lax.dot_general(
+    dot = jax.lax.dot_general(
         onehot_t, stats_ref[...].astype(compute_dtype),
         dimension_numbers=(((1,), (0,)), ((), ())),
         precision=precision,
         preferred_element_type=jnp.float32)               # (B, C)
+    # A select, not a branch: the block's load and add then overlap the
+    # matmul (a pl.when pair measured 5% slower per step on a v5e).  On a
+    # node's first tile the block holds stale data, which is not selected.
+    out_ref[...] = jnp.where(first, dot, out_ref[...] + dot)
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("n_bins", "row_tile", "hist_dtype", "interpret"))
-def hist_tiles_pallas(codes_t: jax.Array, stats: jax.Array, *, n_bins: int,
+    static_argnames=("n_nodes", "n_bins", "row_tile", "hist_dtype",
+                     "interpret"))
+def hist_tiles_pallas(tile_node: jax.Array, codes_t: jax.Array,
+                      stats: jax.Array, *, n_nodes: int, n_bins: int,
                       row_tile: int = 256, hist_dtype: str = "float32",
                       interpret: bool) -> jax.Array:
-    """Raw per-tile kernel entry (node-contiguous gathered inputs required —
-    use `ops.histogram_splits_level` / `ops.node_histogram`).
+    """Raw node-histogram kernel entry (node-contiguous gathered inputs
+    required — use `ops.histogram_splits_level` / `ops.node_histogram`).
 
     Args:
+      tile_node: (S // row_tile,) int32 node of each tile, sorted, every
+               node in ``[0, n_nodes)`` present at least once (a node's
+               block is written only by its own tiles).
       codes_t: (m, S) transposed bin codes in partition order, S a multiple
                of ``row_tile``; every tile of ``row_tile`` rows belongs to a
                single tree node (padding rows carry zero stats).
@@ -138,14 +155,15 @@ def hist_tiles_pallas(codes_t: jax.Array, stats: jax.Array, *, n_bins: int,
                ``"bfloat16"`` halves the stats-operand bytes feeding the MXU
                (the sketched gradient channel — exactly the traffic the
                paper's sketch already shrinks d -> k); accumulation stays
-               float32 (``preferred_element_type``), and the one-hot side is
-               exact in either dtype, so only the gradient channels round
-               (~2^-8 relative); the count channel is exact for integer
-               weights < 256.  The subtraction-drift bound under bf16 is
-               asserted in tests/test_hist_engine.py next to the fp32 bound.
+               float32 (``preferred_element_type`` and the float32 output
+               block), and the one-hot side is exact in either dtype, so
+               only the gradient channels round (~2^-8 relative); the count
+               channel is exact for integer weights < 256.  The
+               subtraction-drift bound under bf16 is asserted in
+               tests/test_hist_engine.py next to the fp32 bound.
     Returns:
-      (m, S // row_tile, n_bins, C) float32 per-tile histograms; the caller
-      segment-sums tiles into nodes.
+      (m, n_nodes, n_bins, C) float32 node histograms: each node's tiles
+      summed in tile order.
     """
     m, s = codes_t.shape
     c = stats.shape[1]
@@ -158,19 +176,26 @@ def hist_tiles_pallas(codes_t: jax.Array, stats: jax.Array, *, n_bins: int,
     # fp32 stats must not be truncated to one bf16 MXU pass.
     precision = None if bf16 else jax.lax.Precision.HIGHEST
     n_tiles = s // row_tile
-    grid = (m, n_tiles)
-
+    assert tile_node.shape == (n_tiles,)
+    TR.record_hist_level(m, n_tiles, n_nodes)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(m, n_tiles),
+        in_specs=[
+            pl.BlockSpec((None, 1, row_tile), lambda f, t, node: (f, 0, t)),
+            pl.BlockSpec((row_tile, c), lambda f, t, node: (t, 0)),
+        ],
+        out_specs=pl.BlockSpec((None, None, n_bins, c),
+                               lambda f, t, node: (f, node[t], 0, 0)))
     return pl.pallas_call(
         functools.partial(_hist_tiles_kernel, n_bins=n_bins,
                           compute_dtype=compute_dtype, precision=precision),
+        grid_spec=grid_spec,
         name="hist_tiles_pallas",
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((None, 1, row_tile), lambda f, t: (f, 0, t)),
-            pl.BlockSpec((row_tile, c), lambda f, t: (t, 0)),
-        ],
-        out_specs=pl.BlockSpec((None, None, n_bins, c),
-                               lambda f, t: (f, t, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((m, n_tiles, n_bins, c), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((m, n_nodes, n_bins, c), jnp.float32),
+        # A node's block is revisited by consecutive tiles: the tile axis
+        # must run in order on one core.
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(codes_t.reshape(m, 1, s), stats)
+    )(tile_node.astype(jnp.int32), codes_t.reshape(m, 1, s), stats)

@@ -163,9 +163,11 @@ def _tile_plan(counts: jax.Array, build_counts: jax.Array, *, n: int,
                n_tiles: int, row_tile: int):
     """Node-contiguous tile layout for the partitioned histogram kernel.
 
-    Every node gets ``max(ceil(build_counts / row_tile), 1)`` tiles (one
-    all-padding tile keeps unbuilt/empty nodes' output blocks deterministic),
-    so each tile belongs to exactly one node.  Returns ``(tile_node,
+    Every node gets ``max(ceil(build_counts / row_tile), 1)`` tiles (the
+    kernel writes a node's output block only from that node's tiles, so an
+    unbuilt/empty node gets one all-padding tile, which writes zeros), so
+    each tile belongs to exactly one node; the trailing tiles beyond the
+    last node's run map to the last node and carry zero stats.  Returns ``(tile_node,
     src_perm, valid)``: the node of each tile, each row slot's index into the
     *partition-ordered* row sequence, and the real-row mask (padding slots
     carry zero stats).  All shapes are static; ``n_tiles`` must be
@@ -205,14 +207,15 @@ def histogram_splits_level(codes: jax.Array, stats: jax.Array,
 
     The node-partitioned replacement for `histogram_splits`: rows are
     gathered into node-contiguous tiles (`_tile_plan` over the grower's
-    loop-carried `core.histogram.LevelState` permutation), the per-tile
-    kernel contracts an ``n_bins``-wide one-hot space — O(n * m * c) per
-    level instead of O(n * m * c * 2^l) — and a jnp epilogue segment-sums
-    tiles into their nodes.  With ``subtract=True`` only the smaller child
-    of each parent is built (by row count; ties -> left) and the sibling is
-    derived as ``parent − built`` from ``prev_hist``, the previous level's
-    returned histograms — halving the tile work again and keeping the count
-    channel of the directly-built side exact.
+    loop-carried `core.histogram.LevelState` permutation), and the tiles
+    kernel contracts an ``n_bins``-wide one-hot space per tile — O(n * m * c)
+    per level instead of O(n * m * c * 2^l) — summing each node's tiles in
+    that node's output block, so it returns the node histograms already in
+    the split kernel's feature-major layout.  With ``subtract=True`` only
+    the smaller child of each parent is built (by row count; ties -> left)
+    and the sibling is derived as ``parent − built`` from ``prev_hist``, the
+    previous level's returned histograms — halving the tile work again and
+    keeping the count channel of the directly-built side exact.
 
     Args:
       codes: (n, m); stats: (n, c); order: (n,) partition permutation;
@@ -245,24 +248,21 @@ def histogram_splits_level(codes: jax.Array, stats: jax.Array,
         codes_t = codes[ri].astype(jnp.int32).T              # (m, S)
         stats_g = stats.astype(jnp.float32)[ri] * valid[:, None]
         stats_g = _pad_to(stats_g, lane_pad, axis=1)
-    tiles = hist_tiles_pallas(codes_t, stats_g, n_bins=b_pad,
-                              row_tile=row_tile, hist_dtype=hist_dtype,
-                              interpret=interpret)
+    nodes = hist_tiles_pallas(tile_node, codes_t, stats_g, n_nodes=n_nodes,
+                              n_bins=b_pad, row_tile=row_tile,
+                              hist_dtype=hist_dtype, interpret=interpret)
     with jax.named_scope(TR.EPILOGUE):
-        nodes4 = jax.ops.segment_sum(tiles.transpose(1, 0, 2, 3), tile_node,
-                                     num_segments=n_nodes,
-                                     indices_are_sorted=True)
-        nodes4 = nodes4[:, :, :n_bins, :]                  # (nodes, m, B, C)
+        nodes = nodes[:, :, :n_bins, :]                    # (m, nodes, B, C)
     if subtract:
         with jax.named_scope(TR.SUBTRACT):
-            prev4 = prev_hist.reshape(m, P, n_bins, -1).transpose(1, 0, 2, 3)
-            pairs = nodes4.reshape((P, 2) + nodes4.shape[1:])
-            built4 = jnp.take_along_axis(
-                pairs, side.reshape(P, 1, 1, 1, 1), axis=1)[:, 0]
-            nodes4 = interleave_children(side, built4, prev4 - built4)
+            prev = prev_hist.reshape(m, P, n_bins, -1)
+            pairs = nodes.reshape(m, P, 2, n_bins, -1)
+            built = jnp.where(side.reshape(1, P, 1, 1) == 0,
+                              pairs[:, :, 0], pairs[:, :, 1])
+            nodes = jax.vmap(interleave_children, in_axes=(None, 0, 0))(
+                side, built, prev - built)
     with jax.named_scope(TR.EPILOGUE):
-        hist_native = nodes4.transpose(1, 0, 2, 3).reshape(
-            m, n_nodes * n_bins, -1)
+        hist_native = nodes.reshape(m, n_nodes * n_bins, -1)
         mask = (jnp.ones((m,), jnp.float32) if feature_mask is None
                 else feature_mask.astype(jnp.float32))
         hist_p = _pad_to(hist_native, m_tile, axis=0)
@@ -286,10 +286,10 @@ def node_histogram(codes_g: jax.Array, stats_g: jax.Array, *, n_bins: int,
 
     The leaf-wise grower's kernel-path builder: ``codes_g`` (S, m) /
     ``stats_g`` (S, c) hold ONE node's rows in partition order (padding rows
-    carry zero stats).  Rows are tiled through `hist_tiles_pallas` (every
-    tile trivially belongs to the node) and the per-tile histograms sum in
-    tile order — the same accumulation the level engine's per-node segment
-    sums perform.  Semantics contract: `core.histogram.node_hist_jnp`.
+    carry zero stats).  Rows are tiled through `hist_tiles_pallas` with
+    every tile mapped to node 0, so the kernel sums them in tile order —
+    the same accumulation it performs per node in the level engine.
+    Semantics contract: `core.histogram.node_hist_jnp`.
     """
     c = stats_g.shape[1]
     lane_pad = _resolve_lane_pad(lane_pad, interpret)
@@ -297,10 +297,12 @@ def node_histogram(codes_g: jax.Array, stats_g: jax.Array, *, n_bins: int,
     codes_t = _pad_to(codes_g.T.astype(jnp.int32), row_tile, axis=1)
     stats_p = _pad_to(_pad_to(stats_g.astype(jnp.float32), lane_pad, axis=1),
                       row_tile, axis=0)
-    tiles = hist_tiles_pallas(codes_t, stats_p, n_bins=b_pad,
-                              row_tile=row_tile, hist_dtype=hist_dtype,
-                              interpret=interpret)
-    return jnp.sum(tiles, axis=1)[:, :n_bins, :c]
+    n_tiles = codes_t.shape[1] // row_tile
+    hist = hist_tiles_pallas(jnp.zeros((n_tiles,), jnp.int32), codes_t,
+                             stats_p, n_nodes=1, n_bins=b_pad,
+                             row_tile=row_tile, hist_dtype=hist_dtype,
+                             interpret=interpret)
+    return hist[:, 0, :n_bins, :c]
 
 
 @functools.partial(jax.jit,

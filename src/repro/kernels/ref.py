@@ -42,16 +42,19 @@ def histogram_ref(codes: jax.Array, node_pos: jax.Array, stats: jax.Array,
     return hist.reshape(m, n_nodes, n_bins, -1).transpose(1, 0, 2, 3)
 
 
-@functools.partial(jax.jit, static_argnames=("n_bins", "row_tile"))
-def histogram_tiles_ref(codes_t: jax.Array, stats: jax.Array, *, n_bins: int,
+@functools.partial(jax.jit, static_argnames=("n_nodes", "n_bins",
+                                             "row_tile"))
+def histogram_tiles_ref(tile_node: jax.Array, codes_t: jax.Array,
+                        stats: jax.Array, *, n_nodes: int, n_bins: int,
                         row_tile: int = 256) -> jax.Array:
     """Oracle for the partitioned tiles kernel (`hist_kernel.hist_tiles_pallas`).
 
-    Same contract: (m, S) partition-ordered codes + (S, C) stats ->
-    (m, S // row_tile, n_bins, C) per-tile histograms.  The body is the
-    identical one-hot ``dot_general`` per tile, so the kernel is
-    bit-identical to this oracle (exact 0/1-selection contraction, one fixed
-    op order).
+    Same contract: (n_tiles,) sorted tile->node map, (m, S) partition-ordered
+    codes + (S, C) stats -> (m, n_nodes, n_bins, C) node histograms, each
+    node's per-tile histograms summed in tile order (its first tile taken as
+    it is, each later one added).  The per-tile body is the identical
+    one-hot ``dot_general``, so the kernel is bit-identical to this oracle
+    (exact 0/1-selection contraction, one fixed op order).
     """
     m, s = codes_t.shape
     c = stats.shape[1]
@@ -67,8 +70,19 @@ def histogram_tiles_ref(codes_t: jax.Array, stats: jax.Array, *, n_bins: int,
             precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32))(oh_t)
 
-    out = jax.vmap(per_tile, in_axes=(1, 0))(onehot, stats_r)
-    return out.transpose(1, 0, 2, 3)                       # (m, T, B, C)
+    tiles = jax.vmap(per_tile, in_axes=(1, 0))(onehot, stats_r)  # (T, m, B, C)
+    tile_node = tile_node.astype(jnp.int32)
+    first = jnp.concatenate([jnp.ones((1,), bool),
+                             tile_node[1:] != tile_node[:-1]])
+
+    def fold(acc, xs):                                     # acc (nodes, m, B, C)
+        node, is_first, tile = xs
+        new = jnp.where(is_first, tile, acc[node] + tile)
+        return acc.at[node].set(new), None
+
+    acc = jnp.zeros((n_nodes, m, n_bins, c), jnp.float32)
+    acc, _ = jax.lax.scan(fold, acc, (tile_node, first, tiles))
+    return acc.transpose(1, 0, 2, 3)                       # (m, nodes, B, C)
 
 
 @functools.partial(jax.jit, static_argnames=("n_nodes", "n_bins"))

@@ -22,8 +22,8 @@ scope          code                                               metric
                and jnp modes (`tree`)
 ``gather``     in ``hist``: smaller-child choice, tile plan, row  ``hist_gather_ms``
                gathers of codes and stats, lane pad (`ops`)
-``epilogue``   in ``hist``: tile-to-node ``segment_sum``, bin     ``hist_epilogue_ms``
-               slice, layout for the split kernel (`ops`)
+``epilogue``   in ``hist``: bin slice, feature pad and params     ``hist_epilogue_ms``
+               for the split kernel (`ops`)
 ``subtract``   in ``hist``: parent - built, sibling pick,         ``hist_subtract_ms``
                interleave (`ops`)
 ``route``      splits from the scan's flat result, heap writes,   ``round_route_ms``
@@ -62,13 +62,20 @@ span                       code                                             metr
 ``sketchboost.pack``       `forest.pack_forest`                             idle-gap owner
 =========================  ===============================================  ===================
 
+The histogram kernel's fold is a count made while a program is traced:
+`HistFold` notes, for each shape of ``hist_tiles_pallas`` traced while it
+is on, its grid steps (``m x n_tiles``, one tile's dot each) and the node
+blocks it writes to HBM (``m x n_nodes``).  Steps over blocks is how many
+tile dots fold into each node histogram inside the kernel.  Shapes are
+static, so it adds no device work; nothing in the benchmark reads it yet.
+
 `CompileCounter` counts the executables JAX compiles or loads from the
 persistent cache (``window_programs``, ``setup_compile_s`` in
 `bench/tools/scope_split.py`).
 """
 from __future__ import annotations
 
-from typing import Dict, List
+from typing import Dict, List, Tuple
 
 import jax
 
@@ -96,6 +103,39 @@ SYNC = "sync"
 REPLAY = "replay"
 PACK = "pack"
 SPANS = (FIT, VALIDATE, QUANTILES, BIN, SEGMENT, SYNC, REPLAY, PACK)
+
+
+class HistFold:
+    """How many tile steps of the histogram kernel fold into each node
+    block it writes, for every kernel shape traced while it is on.
+
+    `record_hist_level`, called by ``hist_tiles_pallas`` as it is traced,
+    puts ``(m, n_tiles, n_nodes) -> (m x n_tiles grid steps, m x n_nodes
+    blocks written)`` into ``levels`` of every `HistFold` whose ``with``
+    block is open.  Shapes are static, so this adds no device work; a
+    program JAX has already traced in the process records nothing again,
+    so open it before the first fit.
+    """
+
+    def __init__(self):
+        self.levels: Dict[Tuple[int, int, int], Tuple[int, int]] = {}
+
+    def __enter__(self) -> "HistFold":
+        _HIST_FOLDS.append(self)
+        return self
+
+    def __exit__(self, *exc) -> None:
+        _HIST_FOLDS.remove(self)
+
+
+_HIST_FOLDS: List[HistFold] = []
+
+
+def record_hist_level(m: int, n_tiles: int, n_nodes: int) -> None:
+    """Note one traced shape of the histogram tiles kernel in every open
+    `HistFold`."""
+    for fold in _HIST_FOLDS:
+        fold.levels[(m, n_tiles, n_nodes)] = (m * n_tiles, m * n_nodes)
 
 
 def span(name: str) -> jax.profiler.TraceAnnotation:

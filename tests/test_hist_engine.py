@@ -147,20 +147,69 @@ def test_subtract_count_channel_smaller_side_exact():
 # Pallas tiles kernel vs oracle (bit parity) and fused level op
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("m,tn,tiles,B,c", [
-    (3, 64, 2, 8, 2),
-    (5, 32, 4, 16, 4),
-    (2, 128, 3, 32, 8),
+@pytest.mark.parametrize("m,tn,B,c,tile_node,n_pad", [
+    (3, 64, 8, 2, [0, 1], 0),
+    (5, 32, 16, 4, [0, 0, 1, 1], 0),
+    (2, 128, 32, 8, [0, 0, 1], 0),
+    (4, 32, 16, 4, [0, 1, 2, 3, 4], 0),          # runs of length 1
+    (3, 32, 8, 4, [0, 0, 1, 2, 2, 2, 2], 3),     # trailing all-padding run
+    (2, 64, 16, 4, [0, 0, 0, 0], 0),             # single-node map
 ])
-def test_hist_tiles_kernel_bit_matches_ref(m, tn, tiles, B, c):
-    ks = jax.random.split(jax.random.key(m * tn), 2)
+def test_hist_tiles_kernel_bit_matches_ref(m, tn, B, c, tile_node, n_pad):
+    """The tiles kernel sums each node's tiles in that node's output block,
+    bit for bit as the oracle folds them in tile order; the last ``n_pad``
+    tiles carry zero stats, as the tile plan's trailing padding does."""
+    tiles = len(tile_node)
+    tile_node = jnp.asarray(tile_node, jnp.int32)
+    n_nodes = int(tile_node[-1]) + 1
+    ks = jax.random.split(jax.random.key(m * tn + tiles), 2)
     codes_t = jax.random.randint(ks[0], (m, tn * tiles), 0, B, jnp.int32)
     stats = jax.random.normal(ks[1], (tn * tiles, c), jnp.float32)
+    stats = stats.at[tn * (tiles - n_pad):].set(0.0)
     from repro.kernels.hist_kernel import hist_tiles_pallas
-    out_k = hist_tiles_pallas(codes_t, stats, n_bins=B, row_tile=tn,
-                              interpret=True)
-    out_r = ref.histogram_tiles_ref(codes_t, stats, n_bins=B, row_tile=tn)
+    out_k = hist_tiles_pallas(tile_node, codes_t, stats, n_nodes=n_nodes,
+                              n_bins=B, row_tile=tn, interpret=True)
+    out_r = ref.histogram_tiles_ref(tile_node, codes_t, stats,
+                                    n_nodes=n_nodes, n_bins=B, row_tile=tn)
+    assert out_k.shape == (m, n_nodes, B, c)
     np.testing.assert_array_equal(np.asarray(out_k), np.asarray(out_r))
+    # The oracle is the per-node sum of the rows in its tiles.
+    node_of_row = np.repeat(np.asarray(tile_node), tn)
+    direct = H.build_histograms_jnp(codes_t.T, jnp.asarray(node_of_row),
+                                    stats, n_nodes=n_nodes, n_bins=B)
+    np.testing.assert_allclose(np.asarray(out_r),
+                               np.asarray(direct).transpose(1, 0, 2, 3),
+                               rtol=1e-5, atol=1e-5)
+
+
+def test_hist_level_fold_recorded():
+    """Tracing the level op at otto's training shape (49,502 x 93 rows,
+    9 classes + count, depth 6, subtract engine) records 742 kernel steps
+    folding into 63 node blocks per feature over one tree's levels."""
+    from repro.runtime import tracing as TR
+    n, m, c, B = 49502, 93, 10, 256
+    S = jax.ShapeDtypeStruct
+    with TR.HistFold() as fold:
+        for lvl in range(6):
+            n_nodes = 2 ** lvl
+            prev = (None if lvl == 0
+                    else S((m, n_nodes // 2 * B, 128), jnp.float32))
+            jax.eval_shape(
+                lambda codes, stats, order, counts, prev, lam, md, nn=n_nodes:
+                ops.histogram_splits_level(
+                    codes, stats, order, counts, prev, lam, md, n_nodes=nn,
+                    n_bins=B, subtract=nn > 1, lane_pad=128,
+                    interpret=False),
+                S((n, m), jnp.uint8), S((n, c), jnp.float32),
+                S((n,), jnp.int32), S((n_nodes,), jnp.int32), prev,
+                S((), jnp.float32), S((), jnp.float32))
+    levels = fold.levels
+    assert sorted(k for k in levels) == [
+        (m, 99, 2), (m, 101, 4), (m, 105, 8), (m, 113, 16), (m, 129, 32),
+        (m, 195, 1)]
+    steps = sum(v[0] for v in levels.values())
+    blocks = sum(v[1] for v in levels.values())
+    assert (steps // m, blocks // m) == (742, 63)
 
 
 @pytest.mark.parametrize("subtract", [False, True])
@@ -207,9 +256,12 @@ def test_hist_tiles_kernel_bf16_fp32_accumulation():
     codes_t = jax.random.randint(ks[0], (m, tn * tiles), 0, B, jnp.int32)
     grads = jax.random.normal(ks[1], (tn * tiles, c - 1), jnp.float32)
     stats = jnp.concatenate([grads, jnp.ones((tn * tiles, 1))], axis=1)
-    out_bf = hist_tiles_pallas(codes_t, stats, n_bins=B, row_tile=tn,
+    tile_node = jnp.asarray([0, 0, 1], jnp.int32)
+    kw = dict(n_nodes=2, n_bins=B, row_tile=tn)
+    out_bf = hist_tiles_pallas(tile_node, codes_t, stats, **kw,
                                hist_dtype="bfloat16", interpret=True)
-    out_fp = ref.histogram_tiles_ref(codes_t, stats, n_bins=B, row_tile=tn)
+    out_fp = ref.histogram_tiles_ref(tile_node, codes_t, stats, **kw)
+    assert out_bf.shape == (m, 2, B, c)
     assert out_bf.dtype == jnp.float32
     scale = float(jnp.max(jnp.abs(out_fp)))
     drift = float(jnp.max(jnp.abs(out_bf - out_fp)))
@@ -218,7 +270,7 @@ def test_hist_tiles_kernel_bf16_fp32_accumulation():
     np.testing.assert_array_equal(np.asarray(out_bf[..., -1]),
                                   np.asarray(out_fp[..., -1]))
     with pytest.raises(ValueError):
-        hist_tiles_pallas(codes_t, stats, n_bins=B, row_tile=tn,
+        hist_tiles_pallas(tile_node, codes_t, stats, **kw,
                           hist_dtype="float16", interpret=True)
 
 

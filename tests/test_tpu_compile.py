@@ -52,6 +52,7 @@ def shape(topo):
 def assert_compiles(fn, *args):
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
+    return text
 
 
 @pytest.mark.parametrize("hist_dtype", ["float32", "bfloat16"])
@@ -68,6 +69,31 @@ def test_histogram_splits_level_compiles(shape, hist_dtype, n_nodes):
                     shape((N_ROWS,), jnp.int32), shape((n_nodes,), jnp.int32),
                     shape((M, n_nodes // 2 * B, 128), jnp.float32),
                     shape((), jnp.float32), shape((), jnp.float32))
+
+
+@pytest.mark.parametrize("n_nodes", [1, 32])
+def test_histogram_splits_level_otto_widths_sums_in_kernel(shape, n_nodes):
+    """At otto's widths (93 features, 9 classes + count = 10 channels ->
+    128 lanes, 256 bins, 256-row tiles) the tiles kernel's scalar-prefetched
+    tile->node output map is Mosaic-legal, and the level program sums tiles
+    into nodes in the kernel: no scatter is left in it, not even as the
+    source op of a fusion (op names are kept in the compiled metadata)."""
+    m, c = 93, 10
+
+    def level(codes, stats, order, counts, prev, lam, min_data):
+        return ops.histogram_splits_level(
+            codes, stats, order, counts, prev, lam, min_data,
+            n_nodes=n_nodes, n_bins=B, subtract=n_nodes > 1, row_tile=256,
+            interpret=False)
+
+    prev = (None if n_nodes == 1
+            else shape((m, n_nodes // 2 * B, 128), jnp.float32))
+    text = assert_compiles(level, shape((N_ROWS, m), jnp.uint8),
+                           shape((N_ROWS, c), jnp.float32),
+                           shape((N_ROWS,), jnp.int32),
+                           shape((n_nodes,), jnp.int32), prev,
+                           shape((), jnp.float32), shape((), jnp.float32))
+    assert "scatter" not in text
 
 
 def test_node_histogram_compiles(shape):
