@@ -38,6 +38,12 @@ Methods
 Entry points: `build_sketch` (single device) and `sketch_sharded` (inside
 shard_map); both are consumed by `boosting._boost_round`, which concatenates
 the sketch with the SGB/GOSS weight channel into the split statistics.
+
+Precision: the paper's sketch is a float32 operator, and on a TPU a float32
+matrix product at the default precision is one bfloat16 pass (about 2^-9
+relative), which rounds the split statistics, and so every split gain, as
+coarsely as bfloat16 histograms would.  Every contraction here therefore
+runs at ``Precision.HIGHEST``; the CPU ignores the flag.
 """
 from __future__ import annotations
 
@@ -46,6 +52,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+
+_HI = jax.lax.Precision.HIGHEST    # fp32 sketch: no single bf16 MXU pass
 
 SKETCH_METHODS = ("none", "top_outputs", "random_sampling", "random_projection",
                   "truncated_svd")
@@ -106,7 +114,7 @@ def truncated_svd_projector(G: jax.Array, k: int) -> jax.Array:
     appendix flags this cost — provided as the quality-upper-bound baseline.
     """
     Gf = G.astype(jnp.float32)
-    gram = Gf.T @ Gf                                        # (d, d)
+    gram = jnp.dot(Gf.T, Gf, precision=_HI)                 # (d, d)
     _, vecs = jnp.linalg.eigh(gram)                         # ascending eigenvalues
     return vecs[:, -k:]                                     # (d, k)
 
@@ -141,7 +149,7 @@ def build_sketch(G: jax.Array, *, method: str, k: int,
         Pi = truncated_svd_projector(G, k)
     else:
         raise ValueError(f"unknown sketch method {method!r}")
-    return G.astype(jnp.float32) @ Pi
+    return jnp.dot(G.astype(jnp.float32), Pi, precision=_HI)
 
 
 def sketch_sharded(G_local: jax.Array, *, method: str, k: int,
@@ -196,7 +204,7 @@ def sketch_sharded(G_local: jax.Array, *, method: str, k: int,
         # split search is well-defined even where eigenvectors are sign-
         # ambiguous across runs.
         G_full = jax.lax.all_gather(Gf, model_axis, axis=1, tiled=True)
-        gram = G_full.T @ G_full                            # (d, d) local part
+        gram = jnp.dot(G_full.T, G_full, precision=_HI)     # (d, d) local part
         for ax in data_axes:
             gram = jax.lax.psum(gram, ax)
         _, vecs = jnp.linalg.eigh(gram)
@@ -204,4 +212,4 @@ def sketch_sharded(G_local: jax.Array, *, method: str, k: int,
     else:
         raise ValueError(f"unknown sketch method {method!r}")
     Pi_local = jax.lax.dynamic_slice_in_dim(Pi, shard_index * d_loc, d_loc, axis=0)
-    return jax.lax.psum(Gf @ Pi_local, model_axis)
+    return jax.lax.psum(jnp.dot(Gf, Pi_local, precision=_HI), model_axis)

@@ -4,6 +4,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
+from jax.extend.core import ClosedJaxpr, Jaxpr
 
 from repro.core import sketch as SK
 
@@ -179,3 +180,75 @@ def test_sketch_sharded_matches_single_device(rng):
         ref = SK.build_sketch(G, method=method, k=3, key=key)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=1e-5, atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# Precision: every sketch contraction is float32 (Precision.HIGHEST), since a
+# TPU runs a float32 product at the default precision as one bf16 pass.  The
+# CPU ignores the flag, so the jaxpr is where a missing one shows.
+# ---------------------------------------------------------------------------
+
+SKETCHED = ("random_projection", "top_outputs", "random_sampling",
+            "truncated_svd")
+
+
+def dot_precisions(jaxpr) -> list:
+    """The ``precision`` of every ``dot_general`` in ``jaxpr`` and in the
+    jaxprs nested in its equations (jit, shard_map, custom rules)."""
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            out.append(eqn.params["precision"])
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                if isinstance(sub, ClosedJaxpr):
+                    out += dot_precisions(sub.jaxpr)
+                elif isinstance(sub, Jaxpr):
+                    out += dot_precisions(sub)
+    return out
+
+
+def all_highest(precs) -> bool:
+    hi = jax.lax.Precision.HIGHEST
+    return all(p is not None and all(q == hi for q in p) for p in precs)
+
+
+@pytest.mark.parametrize("method", SKETCHED)
+def test_build_sketch_contractions_at_highest(rng, method):
+    G = jnp.asarray(rand_G(rng, 64, 40))
+    jaxpr = jax.make_jaxpr(
+        lambda G, key: SK.build_sketch(G, method=method, k=5, key=key))(
+            G, jax.random.key(0))
+    precs = dot_precisions(jaxpr.jaxpr)
+    assert precs, f"{method}: no dot_general traced"
+    assert all_highest(precs), (method, precs)
+
+
+@pytest.mark.parametrize("method", SKETCHED)
+def test_sketch_sharded_contractions_at_highest(rng, method):
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as P
+    from repro.launch.mesh import make_mesh
+
+    mesh = make_mesh((1, 1), ("data", "model"))
+    key = jax.random.key(0)
+
+    def local(Gl):
+        return SK.sketch_sharded(Gl, method=method, k=5, key=key, d_global=40)
+
+    f = shard_map(local, mesh=mesh, in_specs=(P("data", "model"),),
+                  out_specs=P("data", None), check_vma=False)
+    jaxpr = jax.make_jaxpr(f)(jnp.asarray(rand_G(rng, 64, 40)))
+    precs = dot_precisions(jaxpr.jaxpr)
+    assert precs, f"{method}: no dot_general traced"
+    assert all_highest(precs), (method, precs)
+
+
+@pytest.mark.parametrize("method,k", [("none", 5), ("random_projection", 40)])
+def test_unsketched_path_makes_no_product(rng, method, k):
+    """``none`` (and any ``k >= d``) returns G itself: no contraction."""
+    G = jnp.asarray(rand_G(rng, 64, 40))
+    jaxpr = jax.make_jaxpr(
+        lambda G, key: SK.build_sketch(G, method=method, k=k, key=key))(
+            G, jax.random.key(0))
+    assert dot_precisions(jaxpr.jaxpr) == []
