@@ -16,7 +16,8 @@ import jax.numpy as jnp
 from repro.kernels import ref
 from repro.kernels.decode_attention import decode_attention_pallas
 from repro.kernels.flash_attention import flash_attention_pallas
-from repro.kernels.hist_kernel import hist_tiles_pallas, histogram_pallas
+from repro.kernels.hist_kernel import (hist_parts, hist_tiles_pallas,
+                                      histogram_pallas, stat_parts)
 from repro.kernels.predict_kernel import (forest_traverse_pallas,
                                           forest_traverse_quant_pallas)
 from repro.kernels.ref import SHAP_BIG_BIN as _SHAP_BIG
@@ -247,10 +248,12 @@ def histogram_splits_level(codes: jax.Array, stats: jax.Array,
         ri = order[src_perm]
         codes_t = codes[ri].astype(jnp.int32).T              # (m, S)
         stats_g = stats.astype(jnp.float32)[ri] * valid[:, None]
-        stats_g = _pad_to(stats_g, lane_pad, axis=1)
-    nodes = hist_tiles_pallas(tile_node, codes_t, stats_g, n_nodes=n_nodes,
-                              n_bins=b_pad, row_tile=row_tile,
-                              hist_dtype=hist_dtype, interpret=interpret)
+        n_parts = hist_parts(hist_dtype)
+        parts = stat_parts(stats_g, n_parts=n_parts, lane_pad=lane_pad)
+    nodes = hist_tiles_pallas(tile_node, codes_t, parts, n_nodes=n_nodes,
+                              n_bins=b_pad, n_channels=c, n_parts=n_parts,
+                              lane_pad=lane_pad, row_tile=row_tile,
+                              interpret=interpret)
     with jax.named_scope(TR.EPILOGUE):
         nodes = nodes[:, :, :n_bins, :]                    # (m, nodes, B, C)
     if subtract:
@@ -295,13 +298,14 @@ def node_histogram(codes_g: jax.Array, stats_g: jax.Array, *, n_bins: int,
     lane_pad = _resolve_lane_pad(lane_pad, interpret)
     b_pad = n_bins + (-n_bins) % 8               # sublane-aligned bin axis
     codes_t = _pad_to(codes_g.T.astype(jnp.int32), row_tile, axis=1)
-    stats_p = _pad_to(_pad_to(stats_g.astype(jnp.float32), lane_pad, axis=1),
-                      row_tile, axis=0)
+    n_parts = hist_parts(hist_dtype)
+    parts = _pad_to(stat_parts(stats_g, n_parts=n_parts, lane_pad=lane_pad),
+                    row_tile, axis=0)
     n_tiles = codes_t.shape[1] // row_tile
     hist = hist_tiles_pallas(jnp.zeros((n_tiles,), jnp.int32), codes_t,
-                             stats_p, n_nodes=1, n_bins=b_pad,
-                             row_tile=row_tile, hist_dtype=hist_dtype,
-                             interpret=interpret)
+                             parts, n_nodes=1, n_bins=b_pad, n_channels=c,
+                             n_parts=n_parts, lane_pad=lane_pad,
+                             row_tile=row_tile, interpret=interpret)
     return hist[:, 0, :n_bins, :c]
 
 

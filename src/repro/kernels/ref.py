@@ -43,46 +43,55 @@ def histogram_ref(codes: jax.Array, node_pos: jax.Array, stats: jax.Array,
 
 
 @functools.partial(jax.jit, static_argnames=("n_nodes", "n_bins",
-                                             "row_tile"))
+                                             "n_channels", "n_parts",
+                                             "lane_pad", "row_tile"))
 def histogram_tiles_ref(tile_node: jax.Array, codes_t: jax.Array,
-                        stats: jax.Array, *, n_nodes: int, n_bins: int,
+                        parts: jax.Array, *, n_nodes: int, n_bins: int,
+                        n_channels: int, n_parts: int, lane_pad: int,
                         row_tile: int = 256) -> jax.Array:
     """Oracle for the partitioned tiles kernel (`hist_kernel.hist_tiles_pallas`).
 
     Same contract: (n_tiles,) sorted tile->node map, (m, S) partition-ordered
-    codes + (S, C) stats -> (m, n_nodes, n_bins, C) node histograms, each
-    node's per-tile histograms summed in tile order (its first tile taken as
-    it is, each later one added).  The per-tile body is the identical
-    one-hot ``dot_general``, so the kernel is bit-identical to this oracle
-    (exact 0/1-selection contraction, one fixed op order).
+    codes + (S, P) bf16 stats parts (``n_parts`` lane groups of
+    ``n_channels``, `hist_kernel.stat_parts`) -> (m, n_nodes, n_bins, C)
+    node histograms, C = ``n_channels`` padded to ``lane_pad``.  Per tile:
+    one bf16 one-hot contraction accumulated in float32; each node's tiles
+    summed in tile order (its first tile taken as it is, each later one
+    added); then each channel's part sums added in group order.  The
+    kernel runs the identical body (exact 0/1 selections, one fixed op
+    order), so it is bit-identical to this oracle.
     """
     m, s = codes_t.shape
-    c = stats.shape[1]
+    c = n_channels
+    lanes = -(-c // lane_pad) * lane_pad
     n_tiles = s // row_tile
     codes_r = codes_t.reshape(m, n_tiles, row_tile).astype(jnp.int32)
-    stats_r = stats.reshape(n_tiles, row_tile, c)
+    parts_r = parts.reshape(n_tiles, row_tile, -1)
     onehot = (codes_r[..., None]
-              == jnp.arange(n_bins, dtype=jnp.int32)).astype(jnp.float32)
+              == jnp.arange(n_bins, dtype=jnp.int32)).astype(jnp.bfloat16)
 
-    def per_tile(oh_t, st_t):                              # (m, TN, B), (TN, C)
+    def per_tile(oh_t, pt_t):                              # (m, TN, B), (TN, P)
         return jax.vmap(lambda oh: jax.lax.dot_general(
-            oh, st_t, dimension_numbers=(((0,), (0,)), ((), ())),
-            precision=jax.lax.Precision.HIGHEST,
-            preferred_element_type=jnp.float32))(oh_t)
+            oh, pt_t, dimension_numbers=(((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32))(oh_t)     # (m, B, P)
 
-    tiles = jax.vmap(per_tile, in_axes=(1, 0))(onehot, stats_r)  # (T, m, B, C)
+    tiles = jax.vmap(per_tile, in_axes=(1, 0))(onehot, parts_r)  # (T, m, B, P)
     tile_node = tile_node.astype(jnp.int32)
     first = jnp.concatenate([jnp.ones((1,), bool),
                              tile_node[1:] != tile_node[:-1]])
 
-    def fold(acc, xs):                                     # acc (nodes, m, B, C)
+    def fold(acc, xs):                                     # acc (nodes, m, B, P)
         node, is_first, tile = xs
         new = jnp.where(is_first, tile, acc[node] + tile)
         return acc.at[node].set(new), None
 
-    acc = jnp.zeros((n_nodes, m, n_bins, c), jnp.float32)
+    acc = jnp.zeros((n_nodes, m, n_bins, parts.shape[1]), jnp.float32)
     acc, _ = jax.lax.scan(fold, acc, (tile_node, first, tiles))
-    return acc.transpose(1, 0, 2, 3)                       # (m, nodes, B, C)
+    hist = acc[..., :c]
+    for k in range(1, n_parts):
+        hist = hist + acc[..., k * c:(k + 1) * c]
+    hist = jnp.pad(hist, ((0, 0),) * 3 + ((0, lanes - c),))
+    return hist.transpose(1, 0, 2, 3)                      # (m, nodes, B, C)
 
 
 @functools.partial(jax.jit, static_argnames=("n_nodes", "n_bins"))

@@ -64,10 +64,12 @@ span                       code                                             metr
 
 The histogram kernel's fold is a count made while a program is traced:
 `HistFold` notes, for each shape of ``hist_tiles_pallas`` traced while it
-is on, its grid steps (``m x n_tiles``, one tile's dot each) and the node
-blocks it writes to HBM (``m x n_nodes``).  Steps over blocks is how many
-tile dots fold into each node histogram inside the kernel.  Shapes are
-static, so it adds no device work; nothing in the benchmark reads it yet.
+is on, its grid steps (``m x n_tiles``, one tile's dot each), the node
+blocks it writes to HBM (``m x n_nodes``), the bf16 parts per statistic
+(3: the exact float32 split, 1: bfloat16 statistics) and the MXU operand
+lanes of each step's one pass.  Steps over blocks is how many tile dots
+fold into each node histogram inside the kernel.  Shapes are static, so it
+adds no device work; nothing in the benchmark reads it yet.
 
 `CompileCounter` counts the executables JAX compiles or loads from the
 persistent cache (``window_programs``, ``setup_compile_s`` in
@@ -75,7 +77,7 @@ persistent cache (``window_programs``, ``setup_compile_s`` in
 """
 from __future__ import annotations
 
-from typing import Dict, List, Tuple
+from typing import Dict, List, NamedTuple, Tuple
 
 import jax
 
@@ -105,20 +107,29 @@ PACK = "pack"
 SPANS = (FIT, VALIDATE, QUANTILES, BIN, SEGMENT, SYNC, REPLAY, PACK)
 
 
+class HistLevel(NamedTuple):
+    """One traced shape of the histogram tiles kernel."""
+    steps: int          # grid steps, m x n_tiles: one tile's MXU pass each
+    blocks: int         # node blocks written, m x n_nodes
+    parts: int          # bf16 parts per statistic: 3 exact, 1 rounded
+    lanes: int          # MXU operand lanes per step (P)
+
+
 class HistFold:
     """How many tile steps of the histogram kernel fold into each node
     block it writes, for every kernel shape traced while it is on.
 
     `record_hist_level`, called by ``hist_tiles_pallas`` as it is traced,
-    puts ``(m, n_tiles, n_nodes) -> (m x n_tiles grid steps, m x n_nodes
-    blocks written)`` into ``levels`` of every `HistFold` whose ``with``
-    block is open.  Shapes are static, so this adds no device work; a
-    program JAX has already traced in the process records nothing again,
-    so open it before the first fit.
+    puts ``(m, n_tiles, n_nodes) -> HistLevel(m x n_tiles grid steps,
+    m x n_nodes blocks written, bf16 parts per statistic, operand lanes)``
+    into ``levels`` of every `HistFold` whose ``with`` block is open.
+    Shapes are static, so this adds no device work; a program JAX has
+    already traced in the process records nothing again, so open it
+    before the first fit.
     """
 
     def __init__(self):
-        self.levels: Dict[Tuple[int, int, int], Tuple[int, int]] = {}
+        self.levels: Dict[Tuple[int, int, int], HistLevel] = {}
 
     def __enter__(self) -> "HistFold":
         _HIST_FOLDS.append(self)
@@ -131,11 +142,13 @@ class HistFold:
 _HIST_FOLDS: List[HistFold] = []
 
 
-def record_hist_level(m: int, n_tiles: int, n_nodes: int) -> None:
+def record_hist_level(m: int, n_tiles: int, n_nodes: int, parts: int,
+                      lanes: int) -> None:
     """Note one traced shape of the histogram tiles kernel in every open
     `HistFold`."""
     for fold in _HIST_FOLDS:
-        fold.levels[(m, n_tiles, n_nodes)] = (m * n_tiles, m * n_nodes)
+        fold.levels[(m, n_tiles, n_nodes)] = HistLevel(
+            m * n_tiles, m * n_nodes, parts, lanes)
 
 
 def span(name: str) -> jax.profiler.TraceAnnotation:

@@ -166,20 +166,24 @@ def test_hist_tiles_kernel_bit_matches_ref(m, tn, B, c, tile_node, n_pad):
     codes_t = jax.random.randint(ks[0], (m, tn * tiles), 0, B, jnp.int32)
     stats = jax.random.normal(ks[1], (tn * tiles, c), jnp.float32)
     stats = stats.at[tn * (tiles - n_pad):].set(0.0)
-    from repro.kernels.hist_kernel import hist_tiles_pallas
-    out_k = hist_tiles_pallas(tile_node, codes_t, stats, n_nodes=n_nodes,
-                              n_bins=B, row_tile=tn, interpret=True)
-    out_r = ref.histogram_tiles_ref(tile_node, codes_t, stats,
-                                    n_nodes=n_nodes, n_bins=B, row_tile=tn)
-    assert out_k.shape == (m, n_nodes, B, c)
+    from repro.kernels.hist_kernel import hist_tiles_pallas, stat_parts
+    kw = dict(n_nodes=n_nodes, n_bins=B, n_channels=c, n_parts=3,
+              lane_pad=8, row_tile=tn)
+    parts = stat_parts(stats, n_parts=3, lane_pad=8)
+    out_k = hist_tiles_pallas(tile_node, codes_t, parts, **kw,
+                              interpret=True)
+    out_r = ref.histogram_tiles_ref(tile_node, codes_t, parts, **kw)
+    assert out_k.shape == (m, n_nodes, B, 8)
     np.testing.assert_array_equal(np.asarray(out_k), np.asarray(out_r))
-    # The oracle is the per-node sum of the rows in its tiles.
+    # The oracle is the per-node sum of the rows in its tiles; lanes past
+    # the channels are zero.
     node_of_row = np.repeat(np.asarray(tile_node), tn)
     direct = H.build_histograms_jnp(codes_t.T, jnp.asarray(node_of_row),
                                     stats, n_nodes=n_nodes, n_bins=B)
-    np.testing.assert_allclose(np.asarray(out_r),
+    np.testing.assert_allclose(np.asarray(out_r)[..., :c],
                                np.asarray(direct).transpose(1, 0, 2, 3),
                                rtol=1e-5, atol=1e-5)
+    assert not np.asarray(out_r)[..., c:].any()
 
 
 def test_hist_level_fold_recorded():
@@ -207,9 +211,119 @@ def test_hist_level_fold_recorded():
     assert sorted(k for k in levels) == [
         (m, 99, 2), (m, 101, 4), (m, 105, 8), (m, 113, 16), (m, 129, 32),
         (m, 195, 1)]
-    steps = sum(v[0] for v in levels.values())
-    blocks = sum(v[1] for v in levels.values())
+    steps = sum(v.steps for v in levels.values())
+    blocks = sum(v.blocks for v in levels.values())
     assert (steps // m, blocks // m) == (742, 63)
+    # Every level runs the exact float32 path: three bf16 parts of the 10
+    # channels in one 128-lane MXU operand per step.
+    assert {(v.parts, v.lanes) for v in levels.values()} == {(3, 128)}
+
+
+def _split_cases():
+    rng = np.random.default_rng(5)
+    mag = np.logspace(-30, 30, 601)
+    wide = np.stack([mag, -mag], axis=1)                   # both signs
+    frac = rng.uniform(1.0, 2.0, wide.shape)               # full mantissas
+    return {
+        "wide": (wide * frac).astype(np.float32),
+        "zeros": np.array([[0.0, -0.0], [0.0, 1.0]], np.float32),
+        "integers": np.stack([np.arange(-2 ** 12, 2 ** 12),
+                              np.arange(2 ** 24 - 2 ** 13, 2 ** 24)],
+                             axis=1).astype(np.float32),
+        "normal": rng.normal(size=(512, 7)).astype(np.float32),
+    }
+
+
+@pytest.mark.parametrize("case", ["wide", "zeros", "integers", "normal"])
+def test_stat_parts_split_is_exact(case):
+    """Three bf16 parts add back to the float32 statistic bit for bit,
+    over magnitudes 1e-30 to 1e30 of both signs, zeros and integer
+    weights; one part is the statistic rounded to bf16."""
+    from repro.kernels.hist_kernel import stat_parts
+    x = _split_cases()[case]
+    s, c = x.shape
+    parts = stat_parts(jnp.asarray(x), n_parts=3, lane_pad=8)
+    assert parts.dtype == jnp.bfloat16
+    assert parts.shape == (s, -(-3 * c // 8) * 8)
+    p = np.asarray(parts.astype(jnp.float32))
+    hi, mid, lo = p[:, :c], p[:, c:2 * c], p[:, 2 * c:3 * c]
+    assert not p[:, 3 * c:].any()
+    np.testing.assert_array_equal(
+        hi.astype(np.float64) + mid + lo, x.astype(np.float64))
+    np.testing.assert_array_equal((hi + mid) + lo, x)
+    one = stat_parts(jnp.asarray(x), n_parts=1, lane_pad=8)
+    np.testing.assert_array_equal(
+        np.asarray(one[:, :c]), np.asarray(jnp.asarray(x, jnp.bfloat16)))
+
+
+@pytest.mark.parametrize("seed,tn,tile_node", [
+    (0, 64, [0, 0, 1, 1, 1]),
+    (1, 32, [0, 1, 1, 2, 3, 3, 3, 3]),
+    (2, 128, [0, 0, 0]),
+])
+def test_hist_tiles_kernel_matches_float64(seed, tn, tile_node):
+    """The one bf16 pass over three parts is float32-accurate: the
+    interpret-mode kernel agrees with a float64 per-node sum to a few
+    float32 ulps of the largest |sum| (one bf16 pass is ~2^-9 off), with
+    the count channel exact."""
+    from repro.kernels.hist_kernel import hist_tiles_pallas, stat_parts
+    rng = np.random.default_rng(seed)
+    m, B, c = 3, 16, 5
+    tiles = len(tile_node)
+    s = tn * tiles
+    codes = rng.integers(0, B, (m, s))
+    grads = (rng.normal(size=(s, c - 1))
+             * 10.0 ** rng.uniform(-3, 3, (s, c - 1)))
+    weights = rng.integers(0, 4, (s, 1)).astype(np.float64)
+    stats = np.concatenate([grads * weights, weights], axis=1).astype(
+        np.float32)
+    n_nodes = tile_node[-1] + 1
+    out = hist_tiles_pallas(
+        jnp.asarray(tile_node, jnp.int32), jnp.asarray(codes, jnp.int32),
+        stat_parts(jnp.asarray(stats), n_parts=3, lane_pad=8),
+        n_nodes=n_nodes, n_bins=B, n_channels=c, n_parts=3, lane_pad=8,
+        row_tile=tn, interpret=True)
+    node = np.repeat(tile_node, tn)
+    want = np.zeros((m, n_nodes, B, c))
+    for f in range(m):
+        np.add.at(want[f], (node, codes[f]), stats.astype(np.float64))
+    got = np.asarray(out, np.float64)
+    assert not got[..., c:].any()
+    scale = np.abs(want).max()
+    assert np.abs(got[..., :c] - want).max() <= 4 * 2.0 ** -23 * scale
+    np.testing.assert_array_equal(got[..., c - 1], want[..., c - 1])
+
+
+@pytest.mark.parametrize("c,hist_dtype,parts,lanes,out_lanes", [
+    (6, "float32", 3, 128, 128),          # dionis: k = 5 + count
+    (10, "float32", 3, 128, 128),         # otto: 9 classes + count
+    (43, "float32", 3, 256, 128),         # 3c just past one lane tile
+    (356, "float32", 3, 1152, 384),       # 355 classes, no sketch
+    (10, "bfloat16", 1, 128, 128),        # one rounded part
+])
+def test_hist_parts_lane_rule(c, hist_dtype, parts, lanes, out_lanes):
+    """Part count follows ``hist_dtype`` and the operand width follows
+    3c: ``round_up(parts * c, 128)`` lanes in one pass, never more lane
+    tiles than half of HIGHEST's six passes over ``round_up(c, 128)``;
+    the traced kernel records both and writes ``round_up(c, 128)``."""
+    from repro.kernels.hist_kernel import (hist_parts, hist_tiles_pallas,
+                                           parts_lanes, stat_parts)
+    from repro.runtime import tracing as TR
+    assert hist_parts(hist_dtype) == parts
+    assert parts_lanes(c, parts, 128) == lanes
+    assert lanes // 128 <= 3 * out_lanes // 128
+    S = jax.ShapeDtypeStruct
+    op = jax.eval_shape(lambda x: stat_parts(x, n_parts=parts, lane_pad=128),
+                        S((512, c), jnp.float32))
+    assert (op.shape, op.dtype) == ((512, lanes), jnp.bfloat16)
+    with TR.HistFold() as fold:
+        out = jax.eval_shape(
+            lambda tn, codes, p: hist_tiles_pallas(
+                tn, codes, p, n_nodes=2, n_bins=256, n_channels=c,
+                n_parts=parts, lane_pad=128, interpret=False),
+            S((2,), jnp.int32), S((7, 512), jnp.int32), op)
+    assert (out.shape, out.dtype) == ((7, 2, 256, out_lanes), jnp.float32)
+    assert fold.levels == {(7, 2, 2): (14, 14, parts, lanes)}
 
 
 @pytest.mark.parametrize("subtract", [False, True])
@@ -250,17 +364,23 @@ def test_hist_tiles_kernel_bf16_fp32_accumulation():
     accumulation stays fp32, so gradient channels match the fp32 oracle to
     bf16 input rounding (~2^-8 relative) and the count channel (small
     integer weights, exact in bf16) stays integer-exact."""
-    from repro.kernels.hist_kernel import hist_tiles_pallas
+    from repro.kernels.hist_kernel import (hist_parts, hist_tiles_pallas,
+                                           stat_parts)
     ks = jax.random.split(jax.random.key(0), 2)
     m, tn, tiles, B, c = 4, 64, 3, 16, 4
     codes_t = jax.random.randint(ks[0], (m, tn * tiles), 0, B, jnp.int32)
     grads = jax.random.normal(ks[1], (tn * tiles, c - 1), jnp.float32)
     stats = jnp.concatenate([grads, jnp.ones((tn * tiles, 1))], axis=1)
     tile_node = jnp.asarray([0, 0, 1], jnp.int32)
-    kw = dict(n_nodes=2, n_bins=B, row_tile=tn)
-    out_bf = hist_tiles_pallas(tile_node, codes_t, stats, **kw,
-                               hist_dtype="bfloat16", interpret=True)
-    out_fp = ref.histogram_tiles_ref(tile_node, codes_t, stats, **kw)
+    kw = dict(n_nodes=2, n_bins=B, n_channels=c, lane_pad=1, row_tile=tn)
+    n_bf, n_fp = hist_parts("bfloat16"), hist_parts("float32")
+    assert (n_bf, n_fp) == (1, 3)
+    out_bf = hist_tiles_pallas(
+        tile_node, codes_t, stat_parts(stats, n_parts=n_bf, lane_pad=1),
+        **kw, n_parts=n_bf, interpret=True)
+    out_fp = ref.histogram_tiles_ref(
+        tile_node, codes_t, stat_parts(stats, n_parts=n_fp, lane_pad=1),
+        **kw, n_parts=n_fp)
     assert out_bf.shape == (m, 2, B, c)
     assert out_bf.dtype == jnp.float32
     scale = float(jnp.max(jnp.abs(out_fp)))
@@ -270,8 +390,7 @@ def test_hist_tiles_kernel_bf16_fp32_accumulation():
     np.testing.assert_array_equal(np.asarray(out_bf[..., -1]),
                                   np.asarray(out_fp[..., -1]))
     with pytest.raises(ValueError):
-        hist_tiles_pallas(tile_node, codes_t, stats, **kw,
-                          hist_dtype="float16", interpret=True)
+        hist_parts("float16")
 
 
 def test_subtraction_drift_bounded_bf16():

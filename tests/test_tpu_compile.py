@@ -10,6 +10,8 @@ Row counts are small: they do not change what the compiler checks.
 The topology is described inside module fixtures, never at import: only
 the test process that runs this file may load the TPU compiler.
 """
+import re
+
 import pytest
 
 import jax
@@ -71,14 +73,18 @@ def test_histogram_splits_level_compiles(shape, hist_dtype, n_nodes):
                     shape((), jnp.float32), shape((), jnp.float32))
 
 
+@pytest.mark.parametrize("m,c", [(93, 10), (60, 6)])
 @pytest.mark.parametrize("n_nodes", [1, 32])
-def test_histogram_splits_level_otto_widths_sums_in_kernel(shape, n_nodes):
+def test_histogram_splits_level_otto_widths_sums_in_kernel(shape, n_nodes,
+                                                           m, c):
     """At otto's widths (93 features, 9 classes + count = 10 channels ->
-    128 lanes, 256 bins, 256-row tiles) the tiles kernel's scalar-prefetched
-    tile->node output map is Mosaic-legal, and the level program sums tiles
-    into nodes in the kernel: no scatter is left in it, not even as the
-    source op of a fusion (op names are kept in the compiled metadata)."""
-    m, c = 93, 10
+    128 lanes, 256 bins, 256-row tiles) and Dionis's (60 features, k = 5 +
+    count = 6 channels) the tiles kernel's scalar-prefetched tile->node
+    output map is Mosaic-legal, it reads the stats as one bf16 operand of
+    128 lanes (three exact parts of each channel) and writes float32 node
+    histograms, and the level program sums tiles into nodes in the kernel:
+    no scatter is left in it, not even as the source op of a fusion (op
+    names are kept in the compiled metadata)."""
 
     def level(codes, stats, order, counts, prev, lam, min_data):
         return ops.histogram_splits_level(
@@ -94,6 +100,11 @@ def test_histogram_splits_level_otto_widths_sums_in_kernel(shape, n_nodes):
                            shape((n_nodes,), jnp.int32), prev,
                            shape((), jnp.float32), shape((), jnp.float32))
     assert "scatter" not in text
+    call, = [line for line in text.splitlines()
+             if "%hist_tiles_pallas" in line and "custom-call(" in line]
+    assert f"= f32[{m},{n_nodes},256,128]" in call
+    operands = call.split("operand_layout_constraints={")[1].split("}")
+    assert re.match(r"bf16\[\d+,128\]", operands[2].lstrip(", ")), call
 
 
 def test_node_histogram_compiles(shape):
