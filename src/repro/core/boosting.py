@@ -74,7 +74,7 @@ class GBDTConfig:
                                          # or explicit "jnp"/"pallas"/"interpret"
     hist_engine: str = "auto"            # "auto"=subtract: partitioned rows +
                                          # sibling subtraction; or explicit
-                                         # "direct"/"partition"/"subtract"
+                                         # "subtract"/"direct" (jnp reference)
     hist_dtype: str = "float32"          # tiles-kernel MXU input dtype;
                                          # "bfloat16" halves stats bytes
                                          # (fp32 accumulation; kernel modes

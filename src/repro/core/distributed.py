@@ -6,11 +6,10 @@ Layout on the production mesh (pod, data, model):
   features m -> optionally sharded over "model" during histogramming
               (``feature_shard=True`` — the hillclimbed layout, see §Perf)
 
-The distributed grower runs the SAME engines as the single-device path —
-the node-partitioned level engine with sibling subtraction (`grow` PR-4),
-the leaf-wise best-first grower (PR-5 via `tree.grow_tree_leafwise` with
-``psum_axes``), both strategies, and every sketch method — with collectives
-inserted at exactly the decision points:
+There is no distributed grower: each shard calls the single-device growers
+(`tree.grow_tree` level-wise, `tree.grow_tree_leafwise` best-first) with
+their row collectives on (``psum_axes``), for both strategies and every
+sketch method, so collectives sit at exactly the decision points:
 
   1. gradients           — local; softmax CE needs a model-axis logsumexp psum.
   2. sketch G_k = G @ Pi — local matmul + psum(model): the paper's technique *is*
@@ -26,11 +25,13 @@ inserted at exactly the decision points:
      sibling is ``parent − built`` from the replicated previous level.
      With ``cfg.dist_hist_compression = "sketch"`` the gradient channels of
      this psum are routed through the JL machinery of
-     `distributed.compression` (`sketched_hist_psum`): psum(G @ Pi) ==
+     `distributed.compression` (`allreduce_hist`): psum(G @ Pi) ==
      psum(G) @ Pi, so compressing before the collective reconstructs the
      same projection of the exact psum at ``(k+1)/(c)`` of the bytes.  The
      count channel is always summed exactly (split legality and
-     smaller-child choices stay exact).
+     smaller-child choices stay exact).  The growers run their jnp paths
+     here: the fused Pallas level op has no collective between its tiles
+     and split kernels.
   4. split search        — replicated (or feature-sharded: local argmax +
      all_gather of per-node winners over "model").
   5. leaf values         — segment-sum on the *full* sharded gradients, psum over
@@ -38,7 +39,8 @@ inserted at exactly the decision points:
 
 Numerics / parity envelope (asserted by tests/test_distributed_parity.py):
 split DECISIONS (features, thresholds, topology) match the single-device
-grower exactly at fixed seeds; histogram and leaf-value BITS match exactly
+grower exactly at fixed seeds, up to exact ties that the two sum orders
+break differently; histogram and leaf-value BITS match exactly
 whenever every fp32 addition is exact (e.g. dyadic-valued gradients — the
 parity suite's bit-identity fixtures) and otherwise differ only by
 reassociation of the psum tree (~1 ulp per level, values asserted to
@@ -60,7 +62,6 @@ from repro.core import forest as FO
 from repro.core import guards as GU
 from repro.core import histogram as H
 from repro.core import sketch as SK
-from repro.core import split as S
 from repro.core import tree as T
 from repro.core.boosting import (GBDTConfig, _as_forest, _concat_chunks,
                                  _check_resume_compat, _resume_cfg_snapshot)
@@ -124,46 +125,12 @@ def sharded_loss_value(loss_name: str, F_local, Y_local, model_axis: str,
         count = jax.lax.psum(jnp.float32(v.size), model_axis)
     else:
         raise ValueError(loss_name)
-    for ax in row_axes:
-        total = jax.lax.psum(total, ax)
-        count = jax.lax.psum(count, ax)
-    return total / count
+    return C.psum_all(total, row_axes) / C.psum_all(count, row_axes)
 
 
 # ---------------------------------------------------------------------------
-# Histogram collectives.
+# Histogram collective payload.
 # ---------------------------------------------------------------------------
-
-def _psum_all(x: jax.Array, axes: Sequence[str]) -> jax.Array:
-    for ax in axes:
-        x = jax.lax.psum(x, ax)
-    return x
-
-
-def sketched_hist_psum(hist: jax.Array, key: jax.Array,
-                       row_axes: Sequence[str], k: int) -> jax.Array:
-    """All-reduce a ``(..., c)`` histogram payload with JL-compressed
-    gradient channels.
-
-    The last axis is ``[g_1 .. g_{c-1} | count]``.  The gradient channels
-    are compressed with the shared-key JL matrix ``Pi`` (replicated for
-    free, same trick as `core.sketch`), psummed at ``k/(c-1)`` of the
-    bytes, and reconstructed by least-squares (`compression.decompress_
-    block` — the contractive projector).  Because both the psum and the
-    sketch are linear, ``psum(g @ Pi) == psum(g) @ Pi``: the reconstruction
-    is the orthogonal projection of the EXACT psum onto colspace(Pi), not a
-    noisy per-shard estimate.  The count channel is always exact, so split
-    legality (``min_data_in_leaf``) and smaller-child choices are
-    unaffected.  When ``c - 1 <= k`` the compressor is the identity and
-    this is an exact psum.
-    """
-    c = hist.shape[-1]
-    g, cnt = hist[..., :-1], hist[..., -1:]
-    sk, Pi, shape = C.compress_block(g.reshape(-1, c - 1), key, k)
-    g_hat = C.decompress_block(_psum_all(sk, row_axes), Pi,
-                               shape).reshape(g.shape)
-    return jnp.concatenate([g_hat, _psum_all(cnt, row_axes)], axis=-1)
-
 
 def round_collective_bytes(cfg: GBDTConfig, m: int, d: int) -> Dict[str, int]:
     """Analytic histogram-collective payload of ONE boosting round (fp32).
@@ -250,19 +217,9 @@ def make_distributed_boost_step(mesh: Mesh, cfg: GBDTConfig, *,
     row_spec = P(row_axes)
     f_spec = P(row_axes, model_axis)
     y_spec = row_spec if cfg.loss == "multiclass" else f_spec
-    engine = H.resolve_hist_engine(cfg.hist_engine)
     comp = cfg.dist_hist_compression
     k_comp = cfg.dist_hist_k_effective
-    depth, B = cfg.depth, cfg.n_bins
-    lam = jnp.float32(cfg.lambda_l2)
-    min_data = jnp.float32(cfg.min_data_in_leaf)
-    min_gain_ = jnp.float32(cfg.min_gain)
     raxes = tuple(row_axes)
-
-    def hist_psum(h, key):
-        if comp == "sketch":
-            return sketched_hist_psum(h, key, raxes, k_comp)
-        return _psum_all(h, raxes)
 
     def maybe_bf16(stats):
         # The tiles kernel rounds its MXU input to bf16 elementwise; the
@@ -272,99 +229,21 @@ def make_distributed_boost_step(mesh: Mesh, cfg: GBDTConfig, *,
             return stats.astype(jnp.bfloat16).astype(jnp.float32)
         return stats
 
-    def grow_levelwise(codes_l, codes_h, stats, f_off, round_key):
-        """Partition-carrying level loop; returns heap arrays + leaf_pos.
-
-        ``codes_h`` is the histogram view of the features (a model-axis
-        slice under ``feature_shard``); routing always uses the full local
-        ``codes_l``.  The per-shard `LevelState` is advanced by the same
-        stable radix step as the single-device engine — node membership is
-        never re-derived from raw rows.
-        """
-        n_loc = codes_l.shape[0]
-        heap_feat = jnp.zeros((2 ** depth - 1,), jnp.int32)
-        heap_thr = jnp.full((2 ** depth - 1,), B - 1, jnp.int32)
-        heap_gain = jnp.zeros((2 ** depth - 1,), jnp.float32)
-        node_pos = jnp.zeros((n_loc,), jnp.int32)
-        state = H.init_level_state(n_loc) if engine != "direct" else None
-        prev_hist = None
-        for lvl in range(depth):
-            n_nodes = 2 ** lvl
-            ck = (jax.random.fold_in(round_key, lvl) if comp == "sketch"
-                  else None)
-            if engine == "subtract" and lvl > 0:
-                # Globally-consistent smaller-child choice from psummed
-                # per-node counts (2^l ints — negligible next to hists).
-                g_counts = _psum_all(state.counts, raxes)
-                side, _ = H.smaller_children(g_counts)
-                # Build ONLY the globally-smaller children, compacted to
-                # parent index over a FULL local buffer: this shard may own
-                # mostly rows of the globally-smaller side, so the
-                # single-device n//2 buffer could silently drop rows.
-                built = H.build_level_built(codes_h, stats, state, side,
-                                            n_nodes=n_nodes, n_bins=B,
-                                            n_build=n_loc)
-                built = hist_psum(built, ck)          # half-size collective
-                hist = H.interleave_children(side, built, prev_hist - built)
-            elif engine == "direct":
-                hist = hist_psum(H.build_histograms_jnp(
-                    codes_h, node_pos, stats, n_nodes=n_nodes, n_bins=B), ck)
-            else:
-                hist = hist_psum(H.build_level_jnp(
-                    codes_h, stats, state, None, n_nodes=n_nodes, n_bins=B,
-                    subtract=False), ck)
-            prev_hist = hist
-            gain = S.split_scores(hist, lam, min_data)
-            sp = S.best_splits(gain, min_gain_)
-            if feature_shard:
-                # Local winner per node -> global winner over the model axis.
-                local_best = jnp.stack(
-                    [sp.gain, (sp.feat + f_off).astype(jnp.float32),
-                     sp.thr.astype(jnp.float32)], axis=-1)     # (nodes, 3)
-                allb = jax.lax.all_gather(local_best, model_axis)
-                winner = jnp.argmax(allb[..., 0], axis=0)      # (nodes,)
-                picked = jnp.take_along_axis(
-                    allb, winner[None, :, None], axis=0)[0]    # (nodes, 3)
-                feat = picked[:, 1].astype(jnp.int32)
-                thr = picked[:, 2].astype(jnp.int32)
-                g_out = picked[:, 0]
-                is_leaf = ~(g_out > cfg.min_gain)
-                feat = jnp.where(is_leaf, 0, feat)
-                thr = jnp.where(is_leaf, B - 1, thr)
-                sp = S.Splits(feat=feat, thr=thr,
-                              gain=jnp.where(is_leaf, 0.0, g_out),
-                              is_leaf=is_leaf)
-            off = n_nodes - 1
-            heap_feat = jax.lax.dynamic_update_slice(heap_feat, sp.feat,
-                                                     (off,))
-            heap_thr = jax.lax.dynamic_update_slice(heap_thr, sp.thr, (off,))
-            heap_gain = jax.lax.dynamic_update_slice(heap_gain, sp.gain,
-                                                     (off,))
-            bits = T.route_bits(codes_l, node_pos, sp.feat, sp.thr)
-            node_pos = node_pos * 2 + bits
-            if state is not None and lvl < depth - 1:
-                state = H.advance_level_state(state, bits)
-        return heap_feat, heap_thr, heap_gain, node_pos
-
-    def leaf_pass(node_pos, G_t, H_t, n_leaves):
-        """Exact full-gradient leaf values: psum over rows only."""
-        n_loc = node_pos.shape[0]
-        g_sum, h_sum = H.leaf_sums(node_pos, G_t, H_t, n_leaves=n_leaves)
-        cover = jax.ops.segment_sum(jnp.ones((n_loc,), jnp.float32),
-                                    node_pos, num_segments=n_leaves)
-        g_sum = _psum_all(g_sum, raxes)
-        h_sum = _psum_all(h_sum, raxes)
-        cover = _psum_all(cover, raxes)
-        return -g_sum / (h_sum + lam), cover
-
-    def grow_leafwise(codes_l, stats, G_t, H_t, comp_key):
-        return T.grow_tree_leafwise(
-            codes_l, stats, G_t, H_t, depth=depth,
-            max_leaves=cfg.max_leaves, n_bins=B, lam=cfg.lambda_l2,
-            min_data_in_leaf=cfg.min_data_in_leaf, min_gain=cfg.min_gain,
-            use_kernel=False, psum_axes=raxes,
-            dist_hist_compression=comp, dist_hist_k=k_comp,
-            collective_key=comp_key)
+    def grow(codes_l, stats, G_t, H_t, comp_key):
+        """One tree from shard-local rows, through the single-device growers
+        with their row collectives on: ``(tree, leaf_pos)``."""
+        kw = dict(depth=cfg.depth, n_bins=cfg.n_bins, lam=cfg.lambda_l2,
+                  min_data_in_leaf=cfg.min_data_in_leaf,
+                  min_gain=cfg.min_gain, use_kernel=False, psum_axes=raxes,
+                  dist_hist_compression=comp, dist_hist_k=k_comp,
+                  collective_key=comp_key)
+        if cfg.growth == "leafwise":
+            return T.grow_tree_leafwise(codes_l, stats, G_t, H_t,
+                                        max_leaves=cfg.max_leaves, **kw)
+        return T.grow_tree(codes_l, stats, G_t, H_t,
+                           hist_engine=cfg.hist_engine,
+                           feature_axis=model_axis if feature_shard else None,
+                           **kw)
 
     def all_bad(flag):
         """Shard-local non-finite flag -> mesh-global (every shard must take
@@ -392,19 +271,11 @@ def make_distributed_boost_step(mesh: Mesh, cfg: GBDTConfig, *,
         comp_key = (jax.random.fold_in(key, 7919) if comp == "sketch"
                     else None)
 
-        if feature_shard:
-            if m % tp:
-                raise ValueError(
-                    f"feature_shard=True needs the feature count ({m}) "
-                    f"divisible by the model axis ({tp}); pad the feature "
-                    "matrix or use feature_shard=False")
-            m_loc = m // tp
-            f_off = jax.lax.axis_index(model_axis) * m_loc
-            codes_h = jax.lax.dynamic_slice_in_dim(codes_l, f_off, m_loc,
-                                                   axis=1)
-        else:
-            f_off = jnp.int32(0)
-            codes_h = codes_l
+        if feature_shard and m % tp:
+            raise ValueError(
+                f"feature_shard=True needs the feature count ({m}) "
+                f"divisible by the model axis ({tp}); pad the feature "
+                "matrix or use feature_shard=False")
 
         if cfg.strategy == "single_tree":
             Gk = SK.sketch_sharded(G, method=cfg.sketch_method,
@@ -422,22 +293,11 @@ def make_distributed_boost_step(mesh: Mesh, cfg: GBDTConfig, *,
             stats = maybe_bf16(stats)
             skip = (GU.skip_scale(bad, cfg.guard_policy)
                     if cfg.guard_policy == "skip_round" else None)
-            if cfg.growth == "leafwise":
-                tree, leaf_pos = grow_leafwise(codes_l, stats, G, Hd,
-                                               comp_key)
-                if skip is not None:
-                    tree = tree._replace(value=tree.value * skip,
-                                         gain=tree.gain * skip)
-                F_new = F_l + cfg.learning_rate * tree.value[leaf_pos]
-                return F_new, tree
-            heap_feat, heap_thr, heap_gain, node_pos = grow_levelwise(
-                codes_l, codes_h, stats, f_off, comp_key)
-            value, cover = leaf_pass(node_pos, G, Hd, 2 ** depth)
+            tree, leaf_pos = grow(codes_l, stats, G, Hd, comp_key)
             if skip is not None:
-                value, heap_gain = value * skip, heap_gain * skip
-            F_new = F_l + cfg.learning_rate * value[node_pos]
-            tree = T.Tree(feat=heap_feat, thr=heap_thr, value=value,
-                          gain=heap_gain, cover=cover)
+                tree = tree._replace(value=tree.value * skip,
+                                     gain=tree.gain * skip)
+            F_new = F_l + cfg.learning_rate * tree.value[leaf_pos]
             return F_new, tree
 
         # one_vs_all: vmap the per-output grower over this shard's output
@@ -447,18 +307,9 @@ def make_distributed_boost_step(mesh: Mesh, cfg: GBDTConfig, *,
         def grow_one(g_j, h_j):
             stats_j = maybe_bf16(jnp.concatenate([g_j[:, None], ones],
                                                  axis=1))
-            if cfg.growth == "leafwise":
-                tree, leaf_pos = grow_leafwise(codes_l, stats_j,
-                                               g_j[:, None], h_j[:, None],
-                                               comp_key)
-                return tree, tree.value[leaf_pos, 0]
-            heap_feat, heap_thr, heap_gain, node_pos = grow_levelwise(
-                codes_l, codes_l, stats_j, f_off, comp_key)
-            value, cover = leaf_pass(node_pos, g_j[:, None], h_j[:, None],
-                                     2 ** depth)
-            tree = T.Tree(feat=heap_feat, thr=heap_thr, value=value,
-                          gain=heap_gain, cover=cover)
-            return tree, value[node_pos, 0]
+            tree, leaf_pos = grow(codes_l, stats_j, g_j[:, None],
+                                  h_j[:, None], comp_key)
+            return tree, tree.value[leaf_pos, 0]
 
         trees, deltas = jax.vmap(grow_one, in_axes=(1, 1))(G, Hd)
         if cfg.guard_policy == "skip_round":

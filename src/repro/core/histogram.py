@@ -1,24 +1,23 @@
 """Gradient histogram accumulation over (node, feature, bin) cells.
 
-This is the GBDT hot spot (Sec. 3.4: O(n * m * k) per tree level).  Two
-builder generations live here:
+This is the GBDT hot spot (Sec. 3.4: O(n * m * k) per tree level).  The
+level-wise grower (`core.tree.grow_tree`) has two engines:
 
-  * the **direct** builder (``build_histograms`` / ``build_histograms_jnp``)
-    scatters every row into the full ``(n_nodes, m, n_bins, c)`` cell space
-    each level — simple, but the Pallas kernel's one-hot space grows with
-    ``n_nodes`` so per-level FLOPs scale O(n * m * c * 2^l);
-  * the **node-partitioned level engine** (`LevelState` + `build_level_jnp`
-    and its fused Pallas twin `kernels.ops.histogram_splits_level`): the
-    grower carries a stable permutation of rows sorted by node (incremental
-    per-level radix partition, fixed shapes) so histogram work touches an
-    ``n_bins``-wide one-hot space per row tile — O(n * m * c) per level —
-    and the **sibling-subtraction** variant builds only the smaller child of
-    each parent directly, deriving the other as ``parent − built`` from the
-    loop-carried previous-level histograms (halving the remaining scatter
-    work; fp32 drift is bounded and asserted by the parity tests).
+  * ``"subtract"`` (``"auto"``, production) — the **node-partitioned level
+    engine**: the grower carries a stable permutation of rows sorted by
+    node (`LevelState`, advanced per level by an O(n) fixed-shape radix
+    step), so histogram work touches an ``n_bins``-wide one-hot space per
+    row — O(n * m * c) per level — and only the smaller child of each
+    parent is built, the sibling derived as ``parent − built`` from the
+    loop-carried previous-level histograms (fp32 drift bounded and
+    asserted by the parity tests).  `build_level_jnp` is its jnp path and
+    `kernels.ops.histogram_splits_level` its fused Pallas twin.
+  * ``"direct"`` — `build_histograms_jnp` rebuilds every node of a level
+    from ``node_pos`` by segment-sum: the reference the engine parity tests
+    compare against, jnp in every kernel mode.
 
-``resolve_hist_engine`` normalises the engine request; `core.tree.grow_tree`
-threads the chosen engine through both the jnp and Pallas branches.
+The leaf-wise grower reuses the same idea per node (`NodePartition`).
+``resolve_hist_engine`` normalises the engine request.
 """
 from __future__ import annotations
 
@@ -31,7 +30,7 @@ import jax.numpy as jnp
 
 KERNEL_MODES = ("jnp", "pallas", "interpret")
 
-HIST_ENGINES = ("direct", "partition", "subtract")
+HIST_ENGINES = ("direct", "subtract")
 
 
 def resolve_kernel_mode(use_kernel) -> str:
@@ -62,10 +61,7 @@ def resolve_hist_engine(engine) -> str:
 
     ``"auto"`` (the default everywhere) resolves to ``"subtract"`` — the
     partitioned builder plus sibling subtraction, the fastest engine on
-    every backend.  ``"partition"`` is the partitioned builder without
-    subtraction (useful to isolate the two effects in benchmarks);
-    ``"direct"`` is the legacy full-rebuild path kept as the exact
-    reference.
+    every backend.  ``"direct"`` is the full-rebuild jnp reference.
     """
     if engine in (None, "auto"):
         return "subtract"
@@ -78,7 +74,7 @@ def resolve_hist_engine(engine) -> str:
 @functools.partial(jax.jit, static_argnames=("n_nodes", "n_bins"))
 def build_histograms_jnp(codes: jax.Array, node_pos: jax.Array, stats: jax.Array,
                          *, n_nodes: int, n_bins: int) -> jax.Array:
-    """Pure-jnp direct histogram builder (also the Pallas oracle).
+    """Pure-jnp direct histogram builder (the engines' reference).
 
     Args:
       codes:    (n, m) uint8/int feature bin codes.
@@ -99,25 +95,6 @@ def build_histograms_jnp(codes: jax.Array, node_pos: jax.Array, stats: jax.Array
 
     hist = jax.vmap(per_feature, in_axes=1)(codes)          # (m, nodes*B, c)
     return hist.reshape(m, n_nodes, n_bins, c).transpose(1, 0, 2, 3)
-
-
-def build_histograms(codes: jax.Array, node_pos: jax.Array, stats: jax.Array,
-                     *, n_nodes: int, n_bins: int, use_kernel=False,
-                     interpret: bool | None = None) -> jax.Array:
-    """Dispatching direct builder.  ``use_kernel`` is a bool or a mode string
-    and ``interpret`` the legacy explicit override — both resolved by the
-    shared `kernels.ops.resolve_dispatch` helper (the same resolution the
-    fused split search, forest traversal, and TreeSHAP entry points use):
-    ``"pallas"`` runs the compiled Mosaic kernel (TPU), ``"interpret"`` the
-    Pallas interpreter, ``"jnp"`` the segment-sum path — the reference
-    implementation, which XLA fuses well on CPU."""
-    from repro.kernels import ops as kops
-    mode, interp = kops.resolve_dispatch(use_kernel, interpret)
-    if mode != "jnp":
-        return kops.histogram(codes, node_pos, stats, n_nodes=n_nodes,
-                              n_bins=n_bins, interpret=interp)
-    return build_histograms_jnp(codes, node_pos, stats, n_nodes=n_nodes,
-                                n_bins=n_bins)
 
 
 # ---------------------------------------------------------------------------
@@ -209,7 +186,8 @@ def interleave_children(side: jax.Array, built4: jax.Array,
     The one place the built-vs-derived placement rule lives: child ``2p``
     is the built histogram iff ``side[p] == 0``.  Shared by the jnp engine,
     the fused Pallas wrapper (`kernels.ops.histogram_splits_level`), and
-    the distributed grower so the three can never disagree.
+    the sharded level loop of `core.tree.grow_tree`, so the three can never
+    disagree.
     """
     P = built4.shape[0]
     s = side.reshape((P,) + (1,) * (built4.ndim - 1))
@@ -225,7 +203,8 @@ def build_level_built(codes: jax.Array, stats: jax.Array, state: LevelState,
     """Compacted built-children accumulation: ``(n_nodes/2, m, n_bins, c)``.
 
     The subtraction engine's direct-build half, factored out so the
-    distributed grower can reuse it with a *globally* chosen ``side``:
+    sharded level loop (`core.tree.grow_tree` with ``psum_axes``) can
+    reduce it before the subtraction, with a *globally* chosen ``side``:
     ``side[p]`` selects which child of parent ``p`` is accumulated.  On one
     device it comes from `smaller_children(state.counts)`; under sharding it
     must come from the psummed global counts — the locally-built child can

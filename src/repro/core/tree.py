@@ -19,6 +19,7 @@ import jax.numpy as jnp
 
 from repro.core import histogram as H
 from repro.core import split as S
+from repro.distributed import compression as C
 from repro.kernels import ref
 from repro.runtime import tracing as TR
 
@@ -50,17 +51,54 @@ def route_level(codes: jax.Array, node_pos: jax.Array, feat: jax.Array,
     return node_pos * 2 + route_bits(codes, node_pos, feat, thr)
 
 
+def _collective_keys(dist_hist_compression: str,
+                     collective_key: Optional[jax.Array]):
+    """Per-build key of the sketched histogram collective: ``i -> key``,
+    ``None`` throughout for the exact psum."""
+    if dist_hist_compression != "sketch":
+        return lambda i: None
+    if collective_key is None:
+        raise ValueError("dist_hist_compression='sketch' needs a "
+                         "collective_key (replicated across shards)")
+    return lambda i: jax.random.fold_in(collective_key, i)
+
+
+def _feature_shard_winner(sp: S.Splits, f_off: jax.Array, axis: str, *,
+                          n_bins: int, min_gain: jax.Array) -> S.Splits:
+    """Local winner per node -> global winner over the feature-shard axis."""
+    local_best = jnp.stack(
+        [sp.gain, (sp.feat + f_off).astype(jnp.float32),
+         sp.thr.astype(jnp.float32)], axis=-1)             # (nodes, 3)
+    allb = jax.lax.all_gather(local_best, axis)
+    winner = jnp.argmax(allb[..., 0], axis=0)              # (nodes,)
+    picked = jnp.take_along_axis(
+        allb, winner[None, :, None], axis=0)[0]            # (nodes, 3)
+    feat = picked[:, 1].astype(jnp.int32)
+    thr = picked[:, 2].astype(jnp.int32)
+    g_out = picked[:, 0]
+    is_leaf = ~(g_out > min_gain)
+    return S.Splits(feat=jnp.where(is_leaf, 0, feat),
+                    thr=jnp.where(is_leaf, n_bins - 1, thr),
+                    gain=jnp.where(is_leaf, 0.0, g_out), is_leaf=is_leaf)
+
+
 @functools.partial(
     jax.jit,
     static_argnames=("depth", "n_bins", "use_kernel", "hist_engine",
-                     "hist_dtype"))
+                     "hist_dtype", "psum_axes", "dist_hist_compression",
+                     "dist_hist_k", "feature_axis"))
 def grow_tree(codes: jax.Array, stats: jax.Array, G: jax.Array, H_diag: jax.Array,
               *, depth: int, n_bins: int, lam: float,
               min_data_in_leaf: float = 1.0, min_gain: float = 0.0,
               feature_mask: Optional[jax.Array] = None,
               use_kernel=False, hist_engine="auto",
-              hist_dtype: str = "float32"):
-    """Grow one multivariate tree (single-device path).
+              hist_dtype: str = "float32",
+              psum_axes: tuple = (),
+              dist_hist_compression: str = "none",
+              dist_hist_k: int = 0,
+              collective_key: Optional[jax.Array] = None,
+              feature_axis: Optional[str] = None):
+    """Grow one multivariate tree level by level.
 
     Args:
       codes:   (n, m) uint8 binned features.
@@ -68,28 +106,64 @@ def grow_tree(codes: jax.Array, stats: jax.Array, G: jax.Array, H_diag: jax.Arra
                carry SGB/GOSS sample weights).
       G, H_diag: (n, d) full gradients / diagonal Hessians for the leaf pass.
       use_kernel: bool or kernel-mode string (see `histogram.resolve_kernel_mode`).
-               Kernel modes run the fused Pallas histogram + split-scan pair per
-               level; the jnp mode builds histograms with segment-sum and scans
-               them with `split.split_scores` / `split.best_splits`.
+               Kernel modes run the fused Pallas tiles + split-scan pair per
+               level (`kernels.ops.histogram_splits_level`); the jnp mode
+               builds histograms with segment-sum and scans them with
+               `split.split_scores` / `split.best_splits`.
       hist_engine: histogram engine (see `histogram.resolve_hist_engine`):
                ``"auto"``/``"subtract"`` carries a node-sorted row partition
                (`histogram.LevelState`) plus the previous level's histograms
                through the level loop, builds only the smaller child of each
-               parent and derives the sibling by subtraction; ``"partition"``
-               partitions without subtraction; ``"direct"`` is the legacy
-               full-rebuild path.
+               parent and derives the sibling by subtraction; ``"direct"``
+               rebuilds every node from ``node_pos`` with the jnp builder in
+               every kernel mode — the reference.
       hist_dtype: MXU input dtype of the partitioned tiles kernel
                (``"float32"`` | ``"bfloat16"``; kernel modes only — the jnp
                path ignores it, which `GBDTConfig.validate` guards against).
+      psum_axes, dist_hist_compression, dist_hist_k, collective_key: row
+               collectives, as in `grow_tree_leafwise`.  Called from inside
+               shard_map (`core.distributed.make_distributed_boost_step`)
+               with ``psum_axes`` set, every histogram, per-node row count
+               and leaf sum is psummed over those row axes, so every shard
+               takes the same split decisions while rows stay sharded.  The
+               smaller child is chosen from the psummed counts and built over
+               a FULL local row buffer (the globally smaller child can hold
+               more than half of one shard's rows); the built children are
+               reduced before the sibling subtraction, through the sketched
+               collective under ``dist_hist_compression="sketch"`` with
+               ``fold_in(collective_key, level)``.  jnp mode only: the fused
+               kernel level op has no collective between its two kernels.
+      feature_axis: mesh axis the histogram feature axis is sharded over
+               (``None``: not sharded).  Each shard builds and scans its
+               ``m / axis_size`` feature slice; the per-node winners are
+               all-gathered over the axis.  Routing reads the full ``codes``.
+               Takes no ``feature_mask``.
     Returns:
       (Tree, leaf_pos) where leaf_pos is the (n,) leaf index of each sample.
     """
     n, m = codes.shape
     mode = H.resolve_kernel_mode(use_kernel)
     engine = H.resolve_hist_engine(hist_engine)
+    sharded = bool(psum_axes)
+    if mode != "jnp" and (sharded or feature_axis is not None):
+        raise ValueError(
+            f"use_kernel={use_kernel!r} with row or feature collectives: the "
+            "fused kernel level op has no collective between its histogram "
+            "and split kernels; grow sharded trees with use_kernel=False")
+    key_of = _collective_keys(dist_hist_compression, collective_key)
     lam = jnp.float32(lam)
     min_data = jnp.float32(min_data_in_leaf)
     min_gain_ = jnp.float32(min_gain)
+
+    codes_h, f_off = codes, None           # histogram view of the features
+    if feature_axis is not None:
+        m_loc = m // jax.lax.axis_size(feature_axis)
+        f_off = jax.lax.axis_index(feature_axis) * m_loc
+        codes_h = jax.lax.dynamic_slice_in_dim(codes, f_off, m_loc, axis=1)
+
+    row_reduce = functools.partial(C.allreduce_hist, row_axes=psum_axes,
+                                   compression=dist_hist_compression,
+                                   k=dist_hist_k)
 
     heap_feat = jnp.zeros((2 ** depth - 1,), jnp.int32)
     heap_thr = jnp.full((2 ** depth - 1,), n_bins - 1, jnp.int32)
@@ -101,38 +175,45 @@ def grow_tree(codes: jax.Array, stats: jax.Array, G: jax.Array, H_diag: jax.Arra
     for lvl in range(depth):
         n_nodes = 2 ** lvl
         subtract = engine == "subtract" and lvl > 0
-        if mode != "jnp":
+        if mode != "jnp" and engine == "subtract":
             from repro.kernels import ops as kops
-            interp = mode == "interpret"
             with jax.named_scope(TR.HIST):
-                if engine == "direct":
-                    best_gain, best_idx = kops.histogram_splits(
-                        codes, node_pos, stats, lam, min_data, feature_mask,
-                        n_nodes=n_nodes, n_bins=n_bins, interpret=interp)
-                else:
-                    best_gain, best_idx, prev_hist = (
-                        kops.histogram_splits_level(
-                            codes, stats, state.order, state.counts,
-                            prev_hist, lam, min_data, feature_mask,
-                            n_nodes=n_nodes, n_bins=n_bins,
-                            subtract=subtract, hist_dtype=hist_dtype,
-                            interpret=interp))
+                best_gain, best_idx, prev_hist = kops.histogram_splits_level(
+                    codes, stats, state.order, state.counts, prev_hist, lam,
+                    min_data, feature_mask, n_nodes=n_nodes, n_bins=n_bins,
+                    subtract=subtract, hist_dtype=hist_dtype,
+                    interpret=mode == "interpret")
             with jax.named_scope(TR.ROUTE):
                 sp = S.splits_from_flat(best_gain, best_idx, n_bins=n_bins,
                                         min_gain=min_gain_)
         else:
             with jax.named_scope(TR.HIST):
                 if engine == "direct":
-                    hist = H.build_histograms_jnp(codes, node_pos, stats,
-                                                  n_nodes=n_nodes,
-                                                  n_bins=n_bins)
+                    hist = row_reduce(H.build_histograms_jnp(
+                        codes_h, node_pos, stats, n_nodes=n_nodes,
+                        n_bins=n_bins), key=key_of(lvl))
+                elif subtract and sharded:
+                    # The globally smaller children, over a FULL local row
+                    # buffer: a shard may own mostly rows of that side, and
+                    # the one-device n // 2 buffer would drop the overflow.
+                    side, _ = H.smaller_children(
+                        C.psum_all(state.counts, psum_axes))
+                    built = row_reduce(H.build_level_built(
+                        codes_h, stats, state, side, n_nodes=n_nodes,
+                        n_bins=n_bins, n_build=n), key=key_of(lvl))
+                    hist = H.interleave_children(side, built,
+                                                 prev_hist - built)
+                    prev_hist = hist
                 else:
-                    hist = H.build_level_jnp(codes, stats, state, prev_hist,
-                                             n_nodes=n_nodes, n_bins=n_bins,
-                                             subtract=subtract)
+                    hist = row_reduce(H.build_level_jnp(
+                        codes_h, stats, state, prev_hist, n_nodes=n_nodes,
+                        n_bins=n_bins, subtract=subtract), key=key_of(lvl))
                     prev_hist = hist
                 gain = S.split_scores(hist, lam, min_data, feature_mask)
                 sp = S.best_splits(gain, min_gain_)
+            if feature_axis is not None:
+                sp = _feature_shard_winner(sp, f_off, feature_axis,
+                                           n_bins=n_bins, min_gain=min_gain_)
         with jax.named_scope(TR.ROUTE):
             off = n_nodes - 1
             heap_feat = jax.lax.dynamic_update_slice(heap_feat, sp.feat,
@@ -149,14 +230,16 @@ def grow_tree(codes: jax.Array, stats: jax.Array, G: jax.Array, H_diag: jax.Arra
         sample_w = stats[:, -1:]                          # SGB/GOSS weights
         g_sum, h_sum = H.leaf_sums(node_pos, G * sample_w, H_diag * sample_w,
                                    n_leaves=2 ** depth)
+        g_sum = C.psum_all(g_sum, psum_axes)   # exact: never sketched
+        h_sum = C.psum_all(h_sum, psum_axes)
         value = -g_sum / (h_sum + lam)
         # Per-leaf cover (weighted training row counts): the substrate for
         # path-dependent TreeSHAP and cover/split-count importances — packed
         # into the serving format by `forest.pack_forest` so explanation
         # needs no re-scan of training data.
-        cover = jax.ops.segment_sum(sample_w[:, 0],
-                                    node_pos.astype(jnp.int32),
-                                    num_segments=2 ** depth)
+        cover = C.psum_all(jax.ops.segment_sum(
+            sample_w[:, 0], node_pos.astype(jnp.int32),
+            num_segments=2 ** depth), psum_axes)
     tree = Tree(feat=heap_feat, thr=heap_thr, value=value, gain=heap_gain,
                 cover=cover)
     return tree, node_pos
@@ -228,9 +311,7 @@ def grow_tree_leafwise(codes: jax.Array, stats: jax.Array, G: jax.Array,
     c = stats.shape[1]
     mode = H.resolve_kernel_mode(use_kernel)
     sharded = bool(psum_axes)
-    if dist_hist_compression == "sketch" and collective_key is None:
-        raise ValueError("dist_hist_compression='sketch' needs a "
-                         "collective_key (replicated across shards)")
+    key_of = _collective_keys(dist_hist_compression, collective_key)
     # Locally-smaller is not globally-smaller: under sharding the built
     # child may own up to ALL of a shard's local rows.
     n_buf = n if sharded else max(n // 2, 1)
@@ -239,24 +320,6 @@ def grow_tree_leafwise(codes: jax.Array, stats: jax.Array, G: jax.Array,
     min_data_ = jnp.float32(min_data_in_leaf)
     min_gain_ = jnp.float32(min_gain)
     neg_inf = jnp.float32(-jnp.inf)
-
-    def _psum(x):
-        for ax in psum_axes:
-            x = jax.lax.psum(x, ax)
-        return x
-
-    def reduce_hist(h, key):
-        """All-reduce one (m, B, c) node histogram over the row axes."""
-        if not sharded:
-            return h
-        if dist_hist_compression == "sketch":
-            from repro.distributed import compression as C
-            g, cnt = h[..., :-1], h[..., -1:]
-            sk, Pi, shape = C.compress_block(g.reshape(-1, c - 1), key,
-                                             dist_hist_k)
-            g = C.decompress_block(_psum(sk), Pi, shape).reshape(g.shape)
-            return jnp.concatenate([g, _psum(cnt)], axis=-1)
-        return _psum(h)
 
     @jax.named_scope(TR.HIST)
     def build_hist(rows, valid, key=None):
@@ -270,7 +333,9 @@ def grow_tree_leafwise(codes: jax.Array, stats: jax.Array, G: jax.Array,
                                     interpret=mode == "interpret")
         else:
             h = H.node_hist_jnp(codes_g, stats_g, n_bins=n_bins)
-        return reduce_hist(h, key)
+        return C.allreduce_hist(h, psum_axes,
+                                compression=dist_hist_compression,
+                                k=dist_hist_k, key=key)
 
     @jax.named_scope(TR.HIST)
     def score(hists, k: int) -> S.Splits:
@@ -287,10 +352,8 @@ def grow_tree_leafwise(codes: jax.Array, stats: jax.Array, G: jax.Array,
         return S.best_splits(gains, min_gain_)
 
     ids = jnp.arange(N, dtype=jnp.int32)
-    root_key = (jax.random.fold_in(collective_key, 0)
-                if dist_hist_compression == "sketch" else None)
     root_hist = build_hist(jnp.arange(n, dtype=jnp.int32),
-                           jnp.ones((n,), jnp.float32), root_key)
+                           jnp.ones((n,), jnp.float32), key_of(0))
     sp0 = score(root_hist[None], 1)
     root_gain = jnp.where(sp0.is_leaf[0] | (depth < 1) | (max_leaves < 2),
                           neg_inf, sp0.gain[0])
@@ -339,12 +402,11 @@ def grow_tree_leafwise(codes: jax.Array, stats: jax.Array, G: jax.Array,
         # Under sharding the choice uses GLOBAL counts so every shard
         # builds (and derives) the same child even where local counts
         # disagree with the global ordering.
-        built_left = _psum(part.counts[c1]) <= _psum(part.counts[c2])
+        built_left = (C.psum_all(part.counts[c1], psum_axes)
+                      <= C.psum_all(part.counts[c2], psum_axes))
         rows, valid = H.gather_node_rows(
             part, jnp.where(built_left, c1, c2), n_buf)
-        exp_key = (jax.random.fold_in(collective_key, t + 1)
-                   if dist_hist_compression == "sketch" else None)
-        built = build_hist(rows, valid.astype(jnp.float32), exp_key)
+        built = build_hist(rows, valid.astype(jnp.float32), key_of(t + 1))
         s_p = s["slot_of"][p]
         with jax.named_scope(TR.HIST), jax.named_scope(TR.SUBTRACT):
             sib = s["cache"][s_p] - built
@@ -390,16 +452,17 @@ def grow_tree_leafwise(codes: jax.Array, stats: jax.Array, G: jax.Array,
         sample_w = stats[:, -1:]
         g_sum, h_sum = H.leaf_sums(leaf_pos, G * sample_w,
                                    H_diag * sample_w, n_leaves=N)
-        g_sum, h_sum = _psum(g_sum), _psum(h_sum)  # exact: never sketched
+        g_sum = C.psum_all(g_sum, psum_axes)   # exact: never sketched
+        h_sum = C.psum_all(h_sum, psum_axes)
         is_term = left == ids
         value = jnp.where(is_term[:, None], -g_sum / (h_sum + lam_), 0.0)
 
         # Node covers bottom-up: children have larger ids, so one reverse
         # sweep makes every internal cover the exact sum of its children
         # (the invariant TreeSHAP's zero-fractions rely on).
-        cover_leaf = _psum(jax.ops.segment_sum(sample_w[:, 0],
-                                               leaf_pos.astype(jnp.int32),
-                                               num_segments=N))
+        cover_leaf = C.psum_all(jax.ops.segment_sum(
+            sample_w[:, 0], leaf_pos.astype(jnp.int32), num_segments=N),
+            psum_axes)
 
         def up(i, cov):
             j = N - 1 - i

@@ -12,11 +12,14 @@ the method stays convergent.
 The same key is used on every pod per step, so Pi is identical everywhere and
 never communicated — the trick that makes the distributed sketch free in
 `core/sketch.py` as well.
+
+The GBDT growers (`core.tree.grow_tree`, `grow_tree_leafwise`) reduce their
+histograms over the row axes through `allreduce_hist`: an exact psum, or one
+whose gradient channels are sketched the same way (`sketched_hist_psum`).
 """
 from __future__ import annotations
 
-import functools
-from typing import Any, Optional, Tuple
+from typing import Any, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -62,6 +65,51 @@ def decompress_block(sketch: jax.Array, Pi: Optional[jax.Array],
         gram + 1e-6 * jnp.eye(gram.shape[0], dtype=gram.dtype),
         sketch.T).T                                      # sketch @ gram^-1
     return (rec @ Pi.T).reshape(shape)
+
+
+def psum_all(x: jax.Array, axes: Sequence[str]) -> jax.Array:
+    """``psum`` over each of ``axes`` in turn (no-op for ``()``)."""
+    for ax in axes:
+        x = jax.lax.psum(x, ax)
+    return x
+
+
+def sketched_hist_psum(hist: jax.Array, key: jax.Array,
+                       row_axes: Sequence[str], k: int) -> jax.Array:
+    """All-reduce a ``(..., c)`` histogram payload with JL-compressed
+    gradient channels.
+
+    The last axis is ``[g_1 .. g_{c-1} | count]``.  The gradient channels
+    are compressed with the shared-key JL matrix ``Pi`` (replicated for
+    free, same trick as `core.sketch`), psummed at ``k/(c-1)`` of the
+    bytes, and reconstructed by least-squares (`decompress_block` — the
+    contractive projector).  Because both the psum and the sketch are
+    linear, ``psum(g @ Pi) == psum(g) @ Pi``: the reconstruction is the
+    orthogonal projection of the EXACT psum onto colspace(Pi), not a noisy
+    per-shard estimate.  The count channel is always exact, so split
+    legality (``min_data_in_leaf``) and smaller-child choices are
+    unaffected.  When ``c - 1 <= k`` the compressor is the identity and
+    this is an exact psum.
+    """
+    c = hist.shape[-1]
+    g, cnt = hist[..., :-1], hist[..., -1:]
+    sk, Pi, shape = compress_block(g.reshape(-1, c - 1), key, k)
+    g_hat = decompress_block(psum_all(sk, row_axes), Pi,
+                             shape).reshape(g.shape)
+    return jnp.concatenate([g_hat, psum_all(cnt, row_axes)], axis=-1)
+
+
+def allreduce_hist(hist: jax.Array, row_axes: Sequence[str], *,
+              compression: str = "none", k: int = 0,
+              key: Optional[jax.Array] = None) -> jax.Array:
+    """The growers' histogram all-reduce over the row axes: exact, or
+    through `sketched_hist_psum` under ``compression="sketch"``.  With no
+    row axes (one device) the histogram is returned as it is."""
+    if not row_axes:
+        return hist
+    if compression == "sketch":
+        return sketched_hist_psum(hist, key, row_axes, k)
+    return psum_all(hist, row_axes)
 
 
 def sketched_psum(grads: Tree, key: jax.Array, axis_name: str, *, k: int = 32,

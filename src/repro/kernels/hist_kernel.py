@@ -1,24 +1,5 @@
 """Pallas TPU kernels: gradient histogram accumulation (the GBDT hot spot).
 
-Two generations:
-
-**Direct kernel** (`histogram_pallas`) — TPU adaptation of Py-Boost's CUDA
-atomic scatter histograms: each grid step builds the one-hot matrix of the
-combined ``(node, bin)`` index for a row tile and contracts it with the
-statistics tile **on the MXU**:
-
-    hist[f, nb_chunk] += onehot(node*B + bin_f - chunk_off)^T  @  stats_tile
-                         (TN, NBC)                                (TN, C)
-
-Grid = (features, nb_chunks, row_tiles); the output block for a given
-(feature, chunk) is revisited across the sequential row-tile axis, which is the
-canonical Pallas accumulation pattern (zero-init at t==0).  VMEM working set per
-step: onehot (TN x NBC x 4B) + stats (TN x C) + out (NBC x C) — with the default
-TN=256, NBC=2048, C<=128 that is ~2.3 MB, comfortably inside 16 MB VMEM while
-keeping MXU-aligned contraction dims (TN multiple of 8, C padded to lanes by
-`ops.histogram`).  Its one-hot space spans ``n_nodes * n_bins`` per row, so
-per-level FLOPs grow with the node count — O(n * m * c * 2^l) at level ``l``.
-
 **Partitioned tiles kernel** (`hist_tiles_pallas`) — the node-partitioned
 engine's hot loop.  `ops.histogram_splits_level` gathers rows into
 node-contiguous tiles (each tile belongs to exactly ONE node; per-node row
@@ -60,67 +41,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.runtime import tracing as TR
-
-
-def _hist_kernel(codes_ref, node_ref, stats_ref, out_ref, *, n_bins: int,
-                 nb_chunk: int):
-    t = pl.program_id(2)
-    nb = pl.program_id(1)
-
-    @pl.when(t == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    code = codes_ref[...].astype(jnp.int32)               # (1, TN)
-    seg = node_ref[...].astype(jnp.int32) * n_bins + code  # (1, TN)
-    rel = seg - nb * nb_chunk
-    tn = code.shape[1]
-    rows = jax.lax.broadcasted_iota(jnp.int32, (nb_chunk, tn), 0)
-    onehot_t = (rel == rows).astype(jnp.float32)          # (NBC, TN)
-    out_ref[...] += jax.lax.dot_general(
-        onehot_t, stats_ref[...],
-        dimension_numbers=(((1,), (0,)), ((), ())),
-        precision=jax.lax.Precision.HIGHEST,
-        preferred_element_type=jnp.float32)               # (NBC, C)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("n_nodes", "n_bins", "row_tile", "nb_chunk", "interpret"))
-def histogram_pallas(codes_t: jax.Array, node_pos: jax.Array, stats: jax.Array,
-                     *, n_nodes: int, n_bins: int, row_tile: int = 256,
-                     nb_chunk: int = 2048, interpret: bool) -> jax.Array:
-    """Raw kernel entry (padded inputs required — use `ops.histogram`).
-
-    Args:
-      codes_t: (m, n) transposed bin codes (feature-major for contiguous tiles).
-      node_pos: (n,) int32; stats: (n, C) float32.  n % row_tile == 0.
-    Returns:
-      (m, n_nodes * n_bins, C) float32 histograms.
-    """
-    m, n = codes_t.shape
-    c = stats.shape[1]
-    nb_total = n_nodes * n_bins
-    nb_chunk = min(nb_chunk, nb_total)
-    assert nb_total % nb_chunk == 0 and n % row_tile == 0
-    grid = (m, nb_total // nb_chunk, n // row_tile)
-
-    # Mosaic blocks the last two dims in (8, 128) tiles or whole: a unit
-    # middle axis makes every (1, row_tile) code / node block legal.
-    return pl.pallas_call(
-        functools.partial(_hist_kernel, n_bins=n_bins, nb_chunk=nb_chunk),
-        name="histogram_pallas",
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((None, 1, row_tile), lambda f, nb, t: (f, 0, t)),
-            pl.BlockSpec((1, row_tile), lambda f, nb, t: (0, t)),
-            pl.BlockSpec((row_tile, c), lambda f, nb, t: (t, 0)),
-        ],
-        out_specs=pl.BlockSpec((None, nb_chunk, c),
-                               lambda f, nb, t: (f, nb, 0)),
-        out_shape=jax.ShapeDtypeStruct((m, nb_total, c), jnp.float32),
-        interpret=interpret,
-    )(codes_t.reshape(m, 1, n), node_pos.reshape(1, n), stats)
 
 
 HIST_DTYPES = ("float32", "bfloat16")

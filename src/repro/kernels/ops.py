@@ -17,7 +17,7 @@ from repro.kernels import ref
 from repro.kernels.decode_attention import decode_attention_pallas
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.hist_kernel import (hist_parts, hist_tiles_pallas,
-                                      histogram_pallas, stat_parts)
+                                      stat_parts)
 from repro.kernels.predict_kernel import (forest_traverse_pallas,
                                           forest_traverse_quant_pallas)
 from repro.kernels.ref import SHAP_BIG_BIN as _SHAP_BIG
@@ -29,10 +29,10 @@ from repro.runtime import tracing as TR
 def resolve_dispatch(use_kernel, interpret: bool | None = None):
     """Shared kernel-dispatch resolution: ``(mode, interpret_flag)``.
 
-    Every kernel-dispatching entry point — `core.histogram.build_histograms`,
-    the fused split search in `core.tree.grow_tree`, the forest traversal
-    (`core.forest.forest_apply`) and TreeSHAP (`explain.shap`) — resolves its
-    ``use_kernel`` request through this one helper so they can never drift.
+    The kernel-dispatching entry points outside the growers — the forest
+    traversal (`core.forest.forest_apply`) and TreeSHAP (`explain.shap`) —
+    resolve their ``use_kernel`` request through this one helper so they
+    can never drift.
     ``interpret`` is the legacy explicit override: with any kernel request
     (even one that auto-resolved to the jnp fallback), ``interpret=True``
     forces the Pallas interpreter and ``interpret=False`` the compiled
@@ -63,36 +63,6 @@ def _pad_to(x: jax.Array, mult: int, axis: int, value=0):
     return jnp.pad(x, widths, constant_values=value)
 
 
-@functools.partial(jax.jit, static_argnames=("n_nodes", "n_bins", "row_tile",
-                                             "nb_chunk", "lane_pad",
-                                             "interpret"))
-def histogram(codes: jax.Array, node_pos: jax.Array, stats: jax.Array, *,
-              n_nodes: int, n_bins: int, row_tile: int = 256,
-              nb_chunk: int = 2048, lane_pad: int | None = None,
-              interpret: bool) -> jax.Array:
-    """(n, m) codes + (n,) nodes + (n, c) stats -> (n_nodes, m, n_bins, c).
-
-    Padded rows carry zero stats (and node 0 / bin 0), contributing nothing.
-    The channel axis is padded to ``lane_pad`` for MXU lane alignment
-    (default: 128 compiled, 8 in interpret mode to stay cheap in tests).
-    """
-    n, m = codes.shape
-    c = stats.shape[1]
-    lane_pad = _resolve_lane_pad(lane_pad, interpret)
-    codes_t = _pad_to(codes.T.astype(jnp.int32), row_tile, axis=1)
-    node_p = _pad_to(node_pos.astype(jnp.int32), row_tile, axis=0)
-    stats_p = _pad_to(_pad_to(stats.astype(jnp.float32), lane_pad, axis=1),
-                      row_tile, axis=0)
-    nb_chunk = min(nb_chunk, n_nodes * n_bins)
-    while (n_nodes * n_bins) % nb_chunk:
-        nb_chunk //= 2
-    hist = histogram_pallas(codes_t, node_p, stats_p, n_nodes=n_nodes,
-                            n_bins=n_bins, row_tile=row_tile,
-                            nb_chunk=nb_chunk, interpret=interpret)
-    hist = hist[:, :, :c]                                  # strip lane padding
-    return hist.reshape(m, n_nodes, n_bins, c).transpose(1, 0, 2, 3)
-
-
 @functools.partial(jax.jit, static_argnames=("n_nodes", "n_bins", "m_tile",
                                              "lane_pad", "interpret"))
 def split_scan(hist: jax.Array, lam: jax.Array, min_data: jax.Array,
@@ -112,46 +82,6 @@ def split_scan(hist: jax.Array, lam: jax.Array, min_data: jax.Array,
             else feature_mask.astype(jnp.float32))
     hist_p = _pad_to(_pad_to(hist.astype(jnp.float32), lane_pad, axis=2),
                      m_tile, axis=0)
-    mask_p = _pad_to(mask, m_tile, axis=0)[:, None]
-    params = jnp.stack([jnp.float32(lam), jnp.float32(min_data)])[None, :]
-    gain, idx = split_scan_pallas(hist_p, params, mask_p, n_nodes=n_nodes,
-                                  n_bins=n_bins, n_channels=c, m_tile=m_tile,
-                                  lane_pad=lane_pad, interpret=interpret)
-    return gain[:, 0], idx[:, 0]
-
-
-@functools.partial(jax.jit, static_argnames=("n_nodes", "n_bins", "row_tile",
-                                             "nb_chunk", "m_tile", "lane_pad",
-                                             "interpret"))
-def histogram_splits(codes: jax.Array, node_pos: jax.Array, stats: jax.Array,
-                     lam: jax.Array, min_data: jax.Array,
-                     feature_mask: jax.Array | None = None, *, n_nodes: int,
-                     n_bins: int, row_tile: int = 256, nb_chunk: int = 2048,
-                     m_tile: int = 8, lane_pad: int | None = None,
-                     interpret: bool):
-    """Fused hot path: histogram kernel -> split-scan kernel, no transpose.
-
-    The intermediate histograms stay in the kernels' native
-    ``(m, n_nodes * n_bins, C)`` layout (lane-padded channels included), so the
-    only host-side work between the two Pallas calls is a feature-axis pad.
-    Returns per-node ``(best_gain, best_idx)`` as in `split_scan`.
-    """
-    n, m = codes.shape
-    c = stats.shape[1]
-    lane_pad = _resolve_lane_pad(lane_pad, interpret)
-    codes_t = _pad_to(codes.T.astype(jnp.int32), row_tile, axis=1)
-    node_p = _pad_to(node_pos.astype(jnp.int32), row_tile, axis=0)
-    stats_p = _pad_to(_pad_to(stats.astype(jnp.float32), lane_pad, axis=1),
-                      row_tile, axis=0)
-    nb_chunk = min(nb_chunk, n_nodes * n_bins)
-    while (n_nodes * n_bins) % nb_chunk:
-        nb_chunk //= 2
-    hist = histogram_pallas(codes_t, node_p, stats_p, n_nodes=n_nodes,
-                            n_bins=n_bins, row_tile=row_tile,
-                            nb_chunk=nb_chunk, interpret=interpret)
-    mask = (jnp.ones((m,), jnp.float32) if feature_mask is None
-            else feature_mask.astype(jnp.float32))
-    hist_p = _pad_to(hist, m_tile, axis=0)
     mask_p = _pad_to(mask, m_tile, axis=0)[:, None]
     params = jnp.stack([jnp.float32(lam), jnp.float32(min_data)])[None, :]
     gain, idx = split_scan_pallas(hist_p, params, mask_p, n_nodes=n_nodes,
@@ -206,11 +136,10 @@ def histogram_splits_level(codes: jax.Array, stats: jax.Array,
                            interpret: bool):
     """Fused partitioned hot path: tiles kernel -> sibling combine -> split scan.
 
-    The node-partitioned replacement for `histogram_splits`: rows are
-    gathered into node-contiguous tiles (`_tile_plan` over the grower's
-    loop-carried `core.histogram.LevelState` permutation), and the tiles
-    kernel contracts an ``n_bins``-wide one-hot space per tile — O(n * m * c)
-    per level instead of O(n * m * c * 2^l) — summing each node's tiles in
+    Rows are gathered into node-contiguous tiles (`_tile_plan` over the
+    grower's loop-carried `core.histogram.LevelState` permutation), and the
+    tiles kernel contracts an ``n_bins``-wide one-hot space per tile —
+    O(n * m * c) per level, at any depth — summing each node's tiles in
     that node's output block, so it returns the node histograms already in
     the split kernel's feature-major layout.  With ``subtract=True`` only
     the smaller child of each parent is built (by row count; ties -> left)
@@ -488,7 +417,6 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
 
 # Re-export the oracles for convenience.
-histogram_ref = ref.histogram_ref
 histogram_tiles_ref = ref.histogram_tiles_ref
 split_scan_ref = ref.split_scan_ref
 forest_apply_ref = ref.forest_apply_ref

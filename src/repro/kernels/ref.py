@@ -27,21 +27,6 @@ def scaled_leaves(lr, leaf: jax.Array) -> jax.Array:
     return jnp.asarray(lr, jnp.float32) * leaf.astype(jnp.float32)
 
 
-@functools.partial(jax.jit, static_argnames=("n_nodes", "n_bins"))
-def histogram_ref(codes: jax.Array, node_pos: jax.Array, stats: jax.Array,
-                  *, n_nodes: int, n_bins: int) -> jax.Array:
-    """(n, m) codes, (n,) nodes, (n, c) stats -> (n_nodes, m, n_bins, c)."""
-    seg_base = node_pos.astype(jnp.int32) * n_bins
-
-    def per_feature(col):
-        seg = seg_base + col.astype(jnp.int32)
-        return jax.ops.segment_sum(stats, seg, num_segments=n_nodes * n_bins)
-
-    hist = jax.vmap(per_feature, in_axes=1)(codes)        # (m, nodes*B, c)
-    m = codes.shape[1]
-    return hist.reshape(m, n_nodes, n_bins, -1).transpose(1, 0, 2, 3)
-
-
 @functools.partial(jax.jit, static_argnames=("n_nodes", "n_bins",
                                              "n_channels", "n_parts",
                                              "lane_pad", "row_tile"))

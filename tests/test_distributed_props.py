@@ -27,8 +27,8 @@ import jax.numpy as jnp
 from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.core import distributed as GD
 from repro.core import histogram as H
+from repro.distributed import compression as C
 
 pytestmark = pytest.mark.skipif(
     jax.device_count() < 8,
@@ -53,7 +53,7 @@ def _advance_many(state, bits_history):
 # 1. LevelState sharding invariants.
 # ---------------------------------------------------------------------------
 
-@settings(max_examples=8)
+@settings(max_examples=8, deadline=None)
 @given(st.integers(0, 10_000), st.integers(1, 3))
 def test_level_state_counts_psum_to_global(seed, depth):
     n = 64
@@ -82,7 +82,7 @@ def test_level_state_counts_psum_to_global(seed, depth):
                                   np.asarray(side_shard))
 
 
-@settings(max_examples=8)
+@settings(max_examples=8, deadline=None)
 @given(st.integers(0, 10_000))
 def test_advance_is_stable_permutation(seed):
     n = 96
@@ -99,7 +99,7 @@ def test_advance_is_stable_permutation(seed):
         np.bincount(nodes, minlength=counts.shape[0]), counts)
 
 
-@settings(max_examples=10)
+@settings(max_examples=10, deadline=None)
 @given(st.integers(2, 32))
 def test_smaller_children_picks_minority(n_pairs):
     rng = np.random.default_rng(n_pairs)
@@ -116,7 +116,7 @@ def test_smaller_children_picks_minority(n_pairs):
                                   np.ones(n_pairs))
 
 
-@settings(max_examples=8)
+@settings(max_examples=8, deadline=None)
 @given(st.integers(1, 8), st.integers(0, 10_000))
 def test_interleave_children_roundtrip(n_pairs, seed):
     rng = np.random.default_rng(seed)
@@ -135,7 +135,7 @@ def test_interleave_children_roundtrip(n_pairs, seed):
 # 2. Sharded compacted build == single-device build (the grower's psum).
 # ---------------------------------------------------------------------------
 
-@settings(max_examples=6)
+@settings(max_examples=6, deadline=None)
 @given(st.integers(0, 10_000), st.integers(1, 2))
 def test_sharded_build_level_built_sums_to_global(seed, depth):
     n, m, B, c = 64, 3, 8, 4
@@ -180,7 +180,7 @@ def _run_hist_psum(hist_global, k):
     key = jax.random.key(0)
 
     def body(h_l, k_arr):
-        return GD.sketched_hist_psum(h_l[0], k_arr, ("rows",), k)[None]
+        return C.sketched_hist_psum(h_l[0], k_arr, ("rows",), k)[None]
 
     fn = jax.jit(shard_map(body, mesh=mesh, in_specs=(P("rows"), P()),
                            out_specs=P("rows")))
@@ -189,7 +189,7 @@ def _run_hist_psum(hist_global, k):
     return out[0]
 
 
-@settings(max_examples=6)
+@settings(max_examples=6, deadline=None)
 @given(st.integers(2, 6), st.integers(0, 10_000))
 def test_sketched_hist_psum_count_channel_exact(c, seed):
     rng = np.random.default_rng(seed)
@@ -203,7 +203,7 @@ def test_sketched_hist_psum_count_channel_exact(c, seed):
                                   np.asarray(hist[..., -1]).sum(0))
 
 
-@settings(max_examples=6)
+@settings(max_examples=6, deadline=None)
 @given(st.integers(1, 4), st.integers(0, 10_000))
 def test_sketched_hist_psum_passthrough_when_wide(c, seed):
     rng = np.random.default_rng(seed)
@@ -213,7 +213,7 @@ def test_sketched_hist_psum_passthrough_when_wide(c, seed):
     np.testing.assert_array_equal(out, np.asarray(hist).sum(0))
 
 
-@settings(max_examples=4)
+@settings(max_examples=4, deadline=None)
 @given(st.integers(0, 10_000))
 def test_sketched_hist_psum_is_projection_of_exact_psum(seed):
     # Linearity: reconstruction == orthogonal projection of the EXACT psum,

@@ -260,22 +260,19 @@ def test_kernel_path_matches_jnp_path():
 
 
 def test_use_kernel_interpret_end_to_end():
-    """Full fit through BOTH Pallas kernels (histogram + split-scan) in
-    interpret mode: functionally equivalent to the jnp path.
+    """Full fit through BOTH Pallas kernels (histogram tiles + split-scan)
+    in interpret mode, on the default engine: functionally equivalent to
+    the jnp path.
 
     The comparison is on predictions, not tree structure: the synthetic
     generator emits duplicated features whose splits tie *exactly*, and the
     kernel's (algebraically equal) accumulation order may break such ties
     toward the twin feature.  Exact per-histogram arg-max parity is asserted
-    in tests/test_kernels.py on shared inputs.  Pinned to the legacy
-    ``direct`` engine, whose kernels are exact 0/1-selection contractions;
-    the partitioned/subtraction engine's cross-mode e2e (where derived
-    siblings carry bounded fp32 drift) lives in tests/test_hist_engine.py.
+    in tests/test_kernels.py on shared inputs.
     """
     X, y = make_tabular("multiclass", 250, 6, 3, seed=8)
     kw = dict(loss="multiclass", n_trees=3, depth=3, learning_rate=0.3,
-              n_bins=32, sketch_method="top_outputs", sketch_k=2,
-              hist_engine="direct")
+              n_bins=32, sketch_method="top_outputs", sketch_k=2)
     m_jnp = SketchBoost(GBDTConfig(use_kernel="jnp", **kw)).fit(X, y)
     m_ker = SketchBoost(GBDTConfig(use_kernel="interpret", **kw)).fit(X, y)
     np.testing.assert_allclose(np.asarray(m_ker.predict_raw(X)),

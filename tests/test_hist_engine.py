@@ -1,14 +1,14 @@
 """Node-partitioned histogram engine: partition invariants, sibling
 subtraction, kernel/oracle parity, and end-to-end engine equivalence.
 
-Tolerance contract (documented in docs/performance.md): the ``partition``
-engine re-orders row summation only, so histograms match ``direct`` to
-float32 accumulation noise (~1e-5 relative); the ``subtract`` engine derives
-each larger sibling as ``parent − built``, whose cancellation error is
-bounded by ``O(eps * ||parent||)`` per cell — 1e-3 absolute at test scales.
-Split *decisions* are identical on all fixed seeds below (near-ties closer
-than the drift bound could legally flip, which is why the legacy
-kernel-vs-jnp e2e in test_gbdt_core.py pins the direct engine).
+Tolerance contract (documented in docs/performance.md): a partitioned build
+of every node (level 0, `build_level_jnp(subtract=False)`) re-orders row
+summation only, so histograms match ``direct`` to float32 accumulation
+noise (~1e-5 relative); the ``subtract`` engine derives each larger sibling
+as ``parent − built``, whose cancellation error is bounded by
+``O(eps * ||parent||)`` per cell — 1e-3 absolute at test scales.  Split
+*decisions* are identical on all fixed seeds below (near-ties closer than
+the drift bound could legally flip).
 """
 import jax
 import jax.numpy as jnp
@@ -96,7 +96,7 @@ def test_smaller_children_selection():
 
 
 # ---------------------------------------------------------------------------
-# jnp engine parity: partition / subtract vs direct histograms
+# jnp engine parity: partitioned / subtract builds vs direct histograms
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("seed,weights", [(0, None), (1, None), (2, "sgb")])
@@ -471,12 +471,11 @@ def test_fused_level_op_lane_padding_zero():
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("mode", ["jnp", "interpret"])
-@pytest.mark.parametrize("engine", ["partition", "subtract"])
-def test_grow_tree_engines_match_direct(mode, engine):
+def test_grow_tree_engines_match_direct(mode):
     codes, stats, G, Hd = _rand_problem(11, n=450, m=8, B=16, depth=4)
     kw = dict(depth=4, n_bins=16, lam=1.0, use_kernel=mode)
     t0, p0 = T.grow_tree(codes, stats, G, Hd, hist_engine="direct", **kw)
-    t1, p1 = T.grow_tree(codes, stats, G, Hd, hist_engine=engine, **kw)
+    t1, p1 = T.grow_tree(codes, stats, G, Hd, hist_engine="subtract", **kw)
     np.testing.assert_array_equal(np.asarray(t0.feat), np.asarray(t1.feat))
     np.testing.assert_array_equal(np.asarray(t0.thr), np.asarray(t1.thr))
     np.testing.assert_allclose(np.asarray(t0.value), np.asarray(t1.value),
@@ -621,10 +620,12 @@ def test_scan_python_loop_parity_under_new_engine():
 def test_hist_engine_resolution():
     assert H.resolve_hist_engine("auto") == "subtract"
     assert H.resolve_hist_engine(None) == "subtract"
+    assert H.HIST_ENGINES == ("direct", "subtract")
     for e in H.HIST_ENGINES:
         assert H.resolve_hist_engine(e) == e
-    with pytest.raises(ValueError):
-        H.resolve_hist_engine("sorted")
+    for bad in ("sorted", "partition"):
+        with pytest.raises(ValueError):
+            H.resolve_hist_engine(bad)
     cfg = GBDTConfig().resolve(4)
     assert cfg.hist_engine == "subtract"
 

@@ -8,42 +8,6 @@ from repro.kernels import ops, ref
 
 
 # ---------------------------------------------------------------------------
-# Histogram kernel
-# ---------------------------------------------------------------------------
-
-@pytest.mark.parametrize("n,m,nodes,B,c", [
-    (64, 3, 1, 8, 2),
-    (256, 8, 4, 16, 4),
-    (300, 5, 8, 16, 6),      # non-multiple row count (padding path)
-    (128, 2, 2, 256, 1),     # full 256-bin histograms
-])
-def test_histogram_kernel_matches_ref(n, m, nodes, B, c):
-    k1, k2, k3 = jax.random.split(jax.random.key(n + m), 3)
-    codes = jax.random.randint(k1, (n, m), 0, B, jnp.int32)
-    node = jax.random.randint(k2, (n,), 0, nodes, jnp.int32)
-    stats = jax.random.normal(k3, (n, c), jnp.float32)
-    h_ref = ref.histogram_ref(codes, node, stats, n_nodes=nodes, n_bins=B)
-    h_ker = ops.histogram(codes, node, stats, n_nodes=nodes, n_bins=B,
-                          interpret=True)
-    np.testing.assert_allclose(np.asarray(h_ker), np.asarray(h_ref),
-                               rtol=1e-5, atol=1e-4)
-
-
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_histogram_kernel_dtypes(dtype):
-    k1, k2, k3 = jax.random.split(jax.random.key(0), 3)
-    codes = jax.random.randint(k1, (128, 4), 0, 16, jnp.int32)
-    node = jax.random.randint(k2, (128,), 0, 2, jnp.int32)
-    stats = jax.random.normal(k3, (128, 3), jnp.float32).astype(dtype)
-    h_ref = ref.histogram_ref(codes, node, stats.astype(jnp.float32),
-                              n_nodes=2, n_bins=16)
-    h_ker = ops.histogram(codes, node, stats.astype(jnp.float32),
-                          n_nodes=2, n_bins=16, interpret=True)
-    np.testing.assert_allclose(np.asarray(h_ker), np.asarray(h_ref),
-                               rtol=1e-3, atol=1e-2)
-
-
-# ---------------------------------------------------------------------------
 # Split-scan kernel
 # ---------------------------------------------------------------------------
 
@@ -119,21 +83,6 @@ def test_split_scan_kernel_no_legal_split():
                                   interpret=True)
     assert np.all(np.asarray(g_ker) == -np.inf)
     assert np.all(np.asarray(i_ker) == 0)
-
-
-def test_fused_histogram_splits_matches_two_step():
-    codes, node, stats, _, hist_mnb, _ = _random_hist_problem(
-        5, 500, 7, 16, 4, 3)
-    lam, min_data = jnp.float32(0.5), jnp.float32(1.0)
-    g_two, i_two = ref.split_scan_ref(hist_mnb, lam, min_data,
-                                      jnp.ones((7,), jnp.float32),
-                                      n_nodes=4, n_bins=16)
-    g_fused, i_fused = ops.histogram_splits(codes, node, stats, lam, min_data,
-                                            n_nodes=4, n_bins=16,
-                                            interpret=True)
-    np.testing.assert_allclose(np.asarray(g_fused), np.asarray(g_two),
-                               rtol=1e-4, atol=1e-4)
-    np.testing.assert_array_equal(np.asarray(i_fused), np.asarray(i_two))
 
 
 def test_grow_tree_kernel_mode_matches_jnp():
